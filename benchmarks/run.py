@@ -60,6 +60,9 @@ def emit(name, value, derived=""):
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=13,
                     help="log2 vertices for the scaled paper graphs")
@@ -255,12 +258,13 @@ def main():
     assert ag["launched"] <= 0.5 * ag["launch_slots"], ag
     emit("async.grid24.gate_skipped", f"{ag['skipped_fraction']:.3f}",
          f"launched={ag['launched']}/{ag['launch_slots']} "
-         f"iters={am['iters']} (8 PEs, overlap+gate)")
+         f"iters={am['iters']} ({am['devices']}, overlap+gate)")
     assert am["measured_ratio"] <= 0.6, am
     emit("async.grid24.collective_ratio", f"{am['measured_ratio']:.3f}",
          f"grouped={am['collective_bytes_measured']['grouped']:.3e} "
          f"full={am['collective_bytes_measured']['full']:.3e} "
-         f"model={am['collective_bytes_model']['ratio']:.3f} (HLO-measured)")
+         f"model={am['collective_bytes_model']['ratio']:.3f} "
+         f"(HLO counts, {am['devices']})")
     cost_json["async"] = {"pe1": at, "gating_model": gm, "grid24_8pe": am}
 
     # ---- out-of-core rectangle streaming (DESIGN.md section 13) ------------

@@ -1,116 +1,47 @@
-"""JAX version compatibility shims.
+"""Mesh and grouped-collective helpers for the installed JAX.
 
-The repo targets the newer JAX API surface (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.set_mesh``); CPU containers
-often ship an older 0.4.x where those live under ``jax.experimental`` or do
-not exist.  All call sites route through this module so the difference is
-absorbed exactly once.
+The repo is written for one JAX release (0.9) and calls ``jax.shard_map``,
+``jax.set_mesh`` and ``jax.sharding.get_abstract_mesh`` directly.  This
+module keeps the mesh conveniences several call sites share, and
+``grouped_reduce``.
 """
 
 from __future__ import annotations
 
-import contextlib
-import os
-
 import jax
 import jax.numpy as jnp
-
-try:  # newer jax: explicit axis types on mesh creation
-    from jax.sharding import AxisType  # noqa: F401
-    _HAS_AXIS_TYPE = True
-except ImportError:
-    AxisType = None
-    _HAS_AXIS_TYPE = False
+from jax.sharding import AxisType
 
 
 def auto_axes(n: int):
-    """``axis_types`` tuple for an all-Auto mesh (None on old jax)."""
-    if not _HAS_AXIS_TYPE:
-        return None
+    """``axis_types`` tuple for an all-Auto mesh."""
     return (AxisType.Auto,) * n
 
 
 def make_mesh(axis_shapes, axis_names, axis_types=None, devices=None):
-    """``jax.make_mesh`` that tolerates old versions without axis_types."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if _HAS_AXIS_TYPE and axis_types is not None:
-        kwargs["axis_types"] = axis_types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        # old name for the same knob: check_rep
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
-
-
-def set_mesh(mesh):
-    """Context manager form of ``jax.set_mesh`` with a Mesh-context fallback."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(mesh, "__enter__"):
-        return mesh  # jax.sharding.Mesh is itself a context manager
-    return contextlib.nullcontext(mesh)
-
-
-def get_abstract_mesh():
-    """Current mesh context (``jax.sharding.get_abstract_mesh`` on new jax,
-    the thread-resources physical mesh on old); None when unset/empty."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    try:
-        from jax._src import mesh as _mesh_lib
-        m = _mesh_lib.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:  # pragma: no cover - private-API drift
-        return None
+    """``jax.make_mesh`` with list-friendly shapes and names."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=axis_types, devices=devices)
 
 
 def mesh_has_manual_axes(mesh) -> bool:
-    """True when any mesh axis is explicitly Manual (new jax only)."""
-    if not _HAS_AXIS_TYPE or not hasattr(mesh, "axis_types"):
-        return False
-    return any(t == AxisType.Manual for t in mesh.axis_types)
-
-
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict: newer jax returns the
-    dict directly, 0.4.x wraps it in a one-element-per-partition list."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """True when any mesh axis is Manual (inside a shard_map body)."""
+    return any(t == AxisType.Manual for t in getattr(mesh, "axis_types", ()))
 
 
 # --------------------------------------------------------------------------
 # Grouped collectives (axis_index_groups under shard_map)
 # --------------------------------------------------------------------------
 #
-# ``jax.lax.psum/pmin(..., axis_index_groups=...)`` is the native way to
-# reduce over a *subset* of an axis (grid2d's column groups).  Support for it
-# inside ``shard_map`` bodies varies by JAX version and by execution mode
-# (interpret-mode shard_map on 0.4.x rejects the kwarg).  ``grouped_reduce``
-# tries the native lowering first and otherwise emulates it with a
-# group-expanded full-axis reduce: each shard scatters its contribution into
-# its own group's row of a ``[n_groups, ...]`` buffer (identity elsewhere),
-# one full-axis reduce combines all groups at once, and the shard reads its
-# group's row back.  Same result, full-axis wire cost -- a correctness
-# fallback, not a performance path.
-#
-# ``REPRO_GROUPED`` selects the mode: ``auto`` (default -- native with
-# fallback), ``native`` (force, surface lowering failures), ``emulate``
-# (force the fallback; CI exercises both).
+# ``jax.lax.psum/pmin(..., axis_index_groups=...)`` reduces over a *subset*
+# of an axis (grid2d's column groups); it lowers to one all-reduce with
+# ``replica_groups`` of the group size.  ``grouped_reduce`` uses it and lets
+# any lowering failure surface.  ``mode="emulate"`` -- asked for by name,
+# only by tests -- instead runs the group-expanded full-axis reduce: each
+# shard scatters its contribution into its own group's row of a
+# ``[n_groups, ...]`` buffer (identity elsewhere), one full-axis reduce
+# combines all groups at once, and the shard reads its group's row back.
+# Same result at full-axis wire cost: a reference, not a path.
 
 _GROUPED_IDENT = {
     "add": lambda dt: jnp.zeros((), dt),
@@ -118,10 +49,6 @@ _GROUPED_IDENT = {
                        if jnp.issubdtype(dt, jnp.floating)
                        else jnp.asarray(jnp.iinfo(dt).max, dt)),
 }
-
-
-def grouped_mode() -> str:
-    return os.environ.get("REPRO_GROUPED", "auto")
 
 
 def _emulated_grouped_reduce(x, axis, groups, combine):
@@ -141,7 +68,7 @@ def _emulated_grouped_reduce(x, axis, groups, combine):
     return full[gid]
 
 
-def grouped_reduce(x, axis, groups, combine, mode=None):
+def grouped_reduce(x, axis, groups, combine, mode="native"):
     """Reduce ``x`` over ``axis`` within each index group.
 
     ``groups`` is a static partition of the axis indices (list of lists,
@@ -152,28 +79,12 @@ def grouped_reduce(x, axis, groups, combine, mode=None):
     """
     if combine not in _GROUPED_IDENT:
         raise ValueError(f"grouped_reduce: unsupported combine {combine!r}")
+    if mode not in ("native", "emulate"):
+        raise ValueError(f"grouped_reduce: unknown mode {mode!r}")
     groups = [list(g) for g in groups]
+    op = jax.lax.psum if combine == "add" else jax.lax.pmin
     if len(groups) == 1:  # degenerate: one group == the whole axis
-        return jax.lax.psum(x, axis) if combine == "add" \
-            else jax.lax.pmin(x, axis)
-    mode = mode or grouped_mode()
-    if mode != "emulate":
-        try:
-            op = jax.lax.psum if combine == "add" else jax.lax.pmin
-            return op(x, axis, axis_index_groups=groups)
-        except (NotImplementedError, ValueError, TypeError):
-            if mode == "native":  # explicit request -- surface the failure
-                raise
-    return _emulated_grouped_reduce(x, axis, groups, combine)
-
-
-def in_manual_region() -> bool:
-    """True inside a shard_map/pmap body on old jax (bound axis names in the
-    axis env).  New jax reports this through the abstract mesh's Manual axis
-    types instead, so this returns False there."""
-    try:
-        from jax._src import core as _core
-        env = _core.get_axis_env()
-        return bool(getattr(env, "axis_sizes", {}))
-    except Exception:
-        return False
+        return op(x, axis)
+    if mode == "emulate":
+        return _emulated_grouped_reduce(x, axis, groups, combine)
+    return op(x, axis, axis_index_groups=groups)

@@ -51,11 +51,6 @@ from repro.core.graph import PartitionedGraph
 
 AXIS = strat.AXIS
 
-# Donating the superstep state buffer lets XLA reuse its allocation for the
-# output across the iteration loop; the CPU backend does not implement
-# donation (it would only warn), so gate on the backend.
-_DONATE = jax.default_backend() in ("tpu", "gpu")
-
 
 def make_pe_mesh(num_pes: int):
     """1-D mesh of chares ("processing elements" in the paper's plots)."""
@@ -413,17 +408,32 @@ class Engine:
                              f"({pg.num_chunks} != {self._C})")
         self._bind(pg)
 
+    def _kernel_device_kind(self) -> str | None:
+        """``device_kind`` the fused kernel would compile for; None off-TPU,
+        where the kernels only run through the Pallas interpreter."""
+        dev = self.mesh.devices.flat[0]
+        return dev.device_kind if dev.platform == "tpu" else None
+
     def _resolve_dispatch(self) -> dict:
         """Resolve ``push_fn='auto'`` against the bound layout's bands.
 
         Returns the recorded decision; sets ``self.push_fn`` to the callable
-        the strategies will actually receive.  The fused Pallas hook is only
-        *installed* on TPU (elsewhere the kernels run through the interpreter
-        -- an emulation, not an execution win); the choice itself is always
-        computed and recorded, which is what the COST harness surfaces.
+        the strategies will actually receive for a 1-D state plane (a
+        batched plane resolves its own width in ``_push_for``).  The choice
+        is the band rule (``blocks.choose_push``: occupancy and in-band
+        tiles per edge block, declining with ``reason`` ``"occupancy"`` or
+        ``"tiles"``); on a TPU the fused kernel is then installed only where
+        its fast-memory footprint (``blocks.fused_footprint``, for the
+        shapes each launch is handed) fits the chip -- otherwise the record
+        says ``staged`` with the ``reason`` ``"vmem"`` or ``"smem"``.
+        Off-TPU the kernels would only
+        run through the interpreter (an emulation, not an execution win), so
+        the staged path runs and the choice is recorded as computed.
         """
         from repro.kernels import blocks
 
+        self._push_by_batch = {}
+        self._fused_geometry = None
         if self._push_request != "auto":
             self.push_fn = self._push_request
             choice = "explicit" if callable(self._push_request) else "staged"
@@ -443,14 +453,55 @@ class Engine:
             scatter = self._C * self._K
         emax = self.pg.edge_valid.shape[1]
         choice, occ = blocks.choose_push(band, emax, self._K, scatter)
-        if choice == "fused" and jax.default_backend() == "tpu":
-            from repro.kernels import ops
+        rec = {"choice": choice, "mode": "auto", "layout": layout,
+               "threshold": blocks.BAND_OCC_FUSED_MAX,
+               "tiles_threshold": blocks.FUSED_TILES_PER_BLOCK_MAX, **occ}
+        if choice == "fused":
+            # a streamed launch sees one window's edge blocks and folds into
+            # a seed accumulator (init=); a resident one sees the whole row
+            stream = self._source is not None
+            self._fused_geometry = {
+                "num_edge_blocks": (self._source.blocks_per_window if stream
+                                    else blocks.num_edge_blocks(emax)),
+                "V": self._K, "S": scatter, "init": stream}
+        self.push_fn = self._resolve_push(None, rec)
+        return rec
 
-            self.push_fn = ops.make_push_fn(interpret=False)
-        else:
-            self.push_fn = None
-        return {"choice": choice, "mode": "auto", "layout": layout,
-                "threshold": blocks.BAND_OCC_FUSED_MAX, **occ}
+    def _resolve_push(self, batch, rec):
+        """Footprint check of the fused kernel for one plane width: returns
+        the hook to install (None = staged) and records the bytes, and any
+        ``reason`` for declining, into ``rec``."""
+        from repro.kernels import blocks
+
+        if self._fused_geometry is None:
+            return None
+        fp = blocks.fused_footprint(**self._fused_geometry, batch=batch)
+        rec["footprint"] = {"vmem_bytes": fp["vmem"],
+                            "smem_bytes": fp["smem"]}
+        kind = self._kernel_device_kind()
+        if kind is None:
+            return None
+        rec["footprint"]["device_kind"] = kind
+        misfit = blocks.fused_misfit(fp, kind)
+        if misfit is not None:
+            rec["choice"], rec["reason"] = "staged", misfit
+            return None
+        from repro.kernels import ops
+
+        return ops.make_push_fn(interpret=False)
+
+    def _push_for(self, vals):
+        """The push hook for a ``[K]`` or batched ``[K, B]`` vals plane: the
+        fused kernel's footprint grows with B, so each batch width is
+        checked on first use and recorded in ``dispatch['batch'][B]``."""
+        if vals.ndim == 1 or self._fused_geometry is None:
+            return self.push_fn
+        B = int(vals.shape[-1])
+        if B not in self._push_by_batch:
+            rec = {"choice": "fused"}
+            self._push_by_batch[B] = self._resolve_push(B, rec)
+            self.dispatch.setdefault("batch", {})[B] = rec
+        return self._push_by_batch[B]
 
     # -- shard_map plumbing -------------------------------------------------
 
@@ -459,7 +510,7 @@ class Engine:
                      for k, v in self.arrays.items()}
         aux_specs = {k: P(AXIS, None) for k in self.aux}
         state_specs = tuple(P(AXIS, None) for _ in range(n_state_in))
-        return compat.shard_map(
+        return jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(arr_specs, aux_specs, *state_specs),
             out_specs=tuple(P(AXIS, None) for _ in range(n_out)),
@@ -470,7 +521,7 @@ class Engine:
         p1, _ = strat.PHASES[self.strategy]
         return p1(vals, arrs, combiner, self._C, self._K,
                   segment_fn=self.segment_fn, edge_value=edge_value,
-                  push_fn=self.push_fn, edge_semiring=edge_semiring,
+                  push_fn=self._push_for(vals), edge_semiring=edge_semiring,
                   grid_meta=self._grid_meta)
 
     def _phase2(self, partial, arrs, combiner):
@@ -824,7 +875,8 @@ class Engine:
             out = strat.grid2d_phase1_window(
                 vals[0], wd, partial[0], comb, self._C, self._K,
                 segment_fn=self.segment_fn, edge_value=program.edge_value,
-                push_fn=self.push_fn, edge_semiring=program.edge_semiring,
+                push_fn=self._push_for(vals[0]),
+                edge_semiring=program.edge_semiring,
                 grid_meta=self._grid_meta)
             return (out[None],)
 
@@ -853,7 +905,7 @@ class Engine:
                     jnp.full((1, 1), changed.astype(jnp.int32)),
                     fb.astype(jnp.int32)[None])
 
-        smap = functools.partial(compat.shard_map, mesh=self.mesh,
+        smap = functools.partial(jax.shard_map, mesh=self.mesh,
                                  check_vma=False)
         prep = jax.jit(smap(prep_body,
                             in_specs=(aux_specs, plane, plane) + qp_specs,
@@ -1079,7 +1131,7 @@ class Engine:
                      for k, v in self.arrays.items()}
         aux_specs = {k: P(AXIS, None) for k in self.aux}
         qp_specs = (P(AXIS, None, None),) if qplane else ()
-        return compat.shard_map(
+        return jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(arr_specs, aux_specs, P(AXIS, None, None),
                       P(AXIS, None, None)) + qp_specs + (P(AXIS, None),),
@@ -1638,10 +1690,12 @@ class Engine:
         fn = self._compiled.get(key)
         if fn is None:
             # the state buffer is consumed by the superstep loop: donate it
-            # so the loop carry reuses its allocation (no-op on CPU)
+            # so the loop carry reuses its allocation.  The CPU backend does
+            # not implement donation (it would only warn), so ask the mesh.
+            donate = self.mesh.devices.flat[0].platform in ("tpu", "gpu")
             fn = jax.jit(self._smap(self._make_body(program, sync, gate),
                                     n_out=3),
-                         donate_argnums=(2,) if _DONATE else ())
+                         donate_argnums=(2,) if donate else ())
             self._compiled[key] = fn
         state, iters, stats = fn(self.arrays, self.aux, s0)
         stats = np.asarray(jax.device_get(stats))

@@ -188,6 +188,18 @@ def frontier_block_mask(frontier: np.ndarray, num_src_blocks: int
 # near-uniform erdos-renyi 0.63-0.97 -- 0.5 splits them with wide margins.
 BAND_OCC_FUSED_MAX = 0.5
 
+# Occupancy is relative: a band that prunes 90% of a quadratic dense grid
+# can still leave a sweep far costlier than the jnp gather/scatter push,
+# whose work grows with the edge count alone.  The fused sweep's work per
+# edge grows with the mean number of in-band tiles each edge block visits,
+# and that number grows with the vertex count on an RMAT layout (about 32
+# at 2^16 vertices, about 1,900 at 2^22).  On a TPU v5 lite at 32.4 tiles
+# per block (the scale-16 soc-lj1-mini stand-in) the fused push took 1.9x
+# (PageRank), 2.2x (SSSP) and 5.4x (BFS) the jnp push's time; at linear
+# cost in the tile count, even the add kernel breaks even below ~17.  Above
+# this cap the sweep loses for every program measured.
+FUSED_TILES_PER_BLOCK_MAX = 16
+
 
 def dense_grid(emax: int, V: int, S: int, chares: int = 1
                ) -> tuple[int, int]:
@@ -223,19 +235,98 @@ def band_occupancy(band: np.ndarray, emax: int, V: int, S: int) -> dict:
     }
 
 
+# ---------------------------------------------------------------------------
+# Fast-memory footprint of the fused kernel (DESIGN.md section 8)
+# ---------------------------------------------------------------------------
+
+# Edge blocks per grid step of the fused kernel: its edge operands move as
+# (EDGE_ROWS, BLOCK_E) int32 tiles, the (8, 128)-aligned 2-D shape Mosaic
+# loads without a relayout.
+EDGE_ROWS = 8
+
+# Fast memory of one TPU core, keyed by jax ``Device.device_kind``.  Source:
+# the XLA:TPU compiler's own capacity report for a described v5e
+# ("Used ... of 128.00M vmem", "Used ... of 1.00M smem").  A device that is
+# not listed is an error, never a default.
+TPU_FAST_MEMORY = {
+    "TPU v5 lite": {"vmem": 128 << 20, "smem": 1 << 20},
+}
+
+# VMEM the kernel body needs beyond its operand buffers: the [BLOCK_E, 256]
+# hit masks and select temporaries of one tile, plus Mosaic's internal
+# scratch.  SMEM_RESERVE is what the compiler keeps for its own scalars
+# next to the prefetched band table; SMEM allocations round up to 512 B.
+VMEM_BODY_BYTES = 2 << 20
+SMEM_RESERVE = 16 << 10
+_SMEM_ALIGN = 512
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def fused_footprint(num_edge_blocks: int, V: int, S: int,
+                    batch: int | None = None, weight: bool = True,
+                    init: bool = False) -> dict:
+    """-> {"vmem": bytes, "smem": bytes} one fused push launch needs.
+
+    Counted from the shapes the kernel is handed: ``num_edge_blocks``
+    BLOCK_E edge blocks (one band-table column each), ``V`` gathered
+    vertices, ``S`` output segments, and the trailing query width
+    ``batch`` (None for a 1-D plane).  The vals, out and (with ``init``)
+    seed planes stay resident in VMEM for the whole sweep, one buffer each,
+    as ``[rows, 256]`` (1-D) or ``[rows, B, 256]`` (batched) 4-byte planes
+    padded to the (8, 128) tile; the 3-4 edge operands are double-buffered
+    (EDGE_ROWS, BLOCK_E) tiles.  The ``[4, NB]`` band table is prefetched
+    into SMEM whole.
+    """
+    def plane(n: int) -> int:
+        rows = -(-n // BLOCK_V)
+        if batch is None:
+            return _round_up(rows, 8) * BLOCK_V * 4
+        return rows * _round_up(batch, 8) * BLOCK_V * 4
+
+    nbp = _round_up(max(num_edge_blocks, 1), EDGE_ROWS)
+    edges = (3 + int(weight)) * 2 * EDGE_ROWS * BLOCK_E * 4
+    vmem = plane(V) + plane(S) * (1 + int(init)) + edges + VMEM_BODY_BYTES
+    smem = _round_up(4 * nbp * 4, _SMEM_ALIGN) + SMEM_RESERVE
+    return {"vmem": vmem, "smem": smem}
+
+
+def fused_misfit(footprint: dict, device_kind: str) -> str | None:
+    """Which fast memory (``"vmem"``/``"smem"``) a fused launch of this
+    ``footprint`` overflows on ``device_kind``, or None when it fits."""
+    if device_kind not in TPU_FAST_MEMORY:
+        raise ValueError(
+            f"no fast-memory capacities for device kind {device_kind!r}; "
+            f"known: {sorted(TPU_FAST_MEMORY)}")
+    cap = TPU_FAST_MEMORY[device_kind]
+    for space in ("vmem", "smem"):
+        if footprint[space] > cap[space]:
+            return space
+    return None
+
+
 def choose_push(band: np.ndarray, emax: int, V: int, S: int
                 ) -> tuple[str, dict]:
     """-> ('fused' | 'staged', occupancy dict): the adaptive dispatch rule.
 
     Fused when the measured bands actually prune on BOTH sides
-    (``max_occupancy <= BAND_OCC_FUSED_MAX``), staged when either side
-    degenerates toward the dense grid -- which side carries the graph
-    signal depends on the layout's sort order, so the rule prices the
-    worse one.
+    (``max_occupancy <= BAND_OCC_FUSED_MAX``) and each edge block visits
+    few enough tiles (``tiles_per_edge_block <= FUSED_TILES_PER_BLOCK_MAX``);
+    staged when either side degenerates toward the dense grid -- which side
+    carries the graph signal depends on the layout's sort order, so the
+    rule prices the worse one -- or when the sweep is too wide to win.  A
+    staged choice names its ``reason``: ``"occupancy"`` or ``"tiles"``.
     """
     occ = band_occupancy(band, emax, V, S)
     occ["max_occupancy"] = max(occ["gather_occupancy"],
                                occ["scatter_occupancy"])
-    choice = ("fused" if occ["max_occupancy"] <= BAND_OCC_FUSED_MAX
-              else "staged")
-    return choice, occ
+    chares = int(band.shape[0]) if band.ndim == 3 else 1
+    occ["tiles_per_edge_block"] = occ["tiles_fused"] / max(
+        chares * band.shape[-1], 1)
+    if occ["max_occupancy"] > BAND_OCC_FUSED_MAX:
+        occ["reason"] = "occupancy"
+    elif occ["tiles_per_edge_block"] > FUSED_TILES_PER_BLOCK_MAX:
+        occ["reason"] = "tiles"
+    return ("staged" if "reason" in occ else "fused"), occ

@@ -10,9 +10,9 @@ Two kernel paths serve the paper's per-chare hot loop:
     with the ``[E]`` intermediate between the halves; kept as the reference
     for the fused-vs-staged comparisons in ``benchmarks.kernelbench``.
 
-On this CPU container kernels always run with ``interpret=True`` (the Pallas
-interpreter executes the kernel body faithfully); on TPU pass
-``interpret=False`` to compile through Mosaic.
+``interpret=None`` (every default here) resolves when the kernel is traced:
+Mosaic on a TPU backend, the Pallas interpreter elsewhere (it executes the
+kernel body faithfully, as an emulation).  Pass a bool to force either.
 """
 
 from __future__ import annotations
@@ -26,7 +26,13 @@ from repro.kernels import push_fused as fused_mod
 from repro.kernels import push_min, push_sum
 from repro.kernels.blocks import BLOCK_E, BLOCK_S, BLOCK_V
 
-_ON_TPU = jax.default_backend() == "tpu"
+
+def _interpret(interpret) -> bool:
+    """``interpret=None`` -> the Pallas interpreter unless JAX's default
+    backend is a TPU.  Asked when the caller traces, never at import."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
 
 
 def _pad_to(x, mult, fill):
@@ -97,7 +103,7 @@ def _push_staged(vals_p, src_p, dst_p, valid_p, weight, nseg_p, combine,
 @partial(jax.jit, static_argnames=("num_segments", "combine", "interpret",
                                    "fused", "unit_weight"))
 def push(vals, src, dst, valid, num_segments, combine="add", weight=None,
-         band=None, interpret=not _ON_TPU, fused=True, unit_weight=False,
+         band=None, interpret=None, fused=True, unit_weight=False,
          init=None):
     """out[s] = combine_{e: dst[e]==s, valid[e]==1} edge_value(vals[src[e]]).
 
@@ -124,6 +130,7 @@ def push(vals, src, dst, valid, num_segments, combine="add", weight=None,
     up to float association for add) -- the streamed window schedule's
     buffer-recycling contract (DESIGN.md section 13).
     """
+    interpret = _interpret(interpret)
     identity = 0 if combine == "add" else push_min.SENTINEL
     vals_p = _pad_to(vals, BLOCK_V, identity)
     src_p = _pad_to(src, BLOCK_E, 0)
@@ -182,7 +189,7 @@ def push(vals, src, dst, valid, num_segments, combine="add", weight=None,
 
 @partial(jax.jit, static_argnames=("num_segments", "combine", "interpret"))
 def segment_reduce(data, seg_ids, num_segments, combine="add",
-                   interpret=not _ON_TPU):
+                   interpret=None):
     """Scatter half only (data already gathered): engine's segment hook.
 
     Integer add data accumulates in its own integer dtype -- the seed cast
@@ -196,6 +203,7 @@ def segment_reduce(data, seg_ids, num_segments, combine="add",
             [segment_reduce(data[:, b], seg_ids, num_segments,
                             combine=combine, interpret=interpret)
              for b in range(data.shape[1])], axis=-1)
+    interpret = _interpret(interpret)
     identity = 0 if combine == "add" else push_min.SENTINEL
     data_p = _pad_to(data, BLOCK_E, identity)
     seg_p = _pad_to(seg_ids, BLOCK_E, 0)
@@ -209,7 +217,7 @@ def segment_reduce(data, seg_ids, num_segments, combine="add",
     return _min_restore_identity(out[:num_segments])
 
 
-def make_segment_fn(interpret=not _ON_TPU, combine=None):
+def make_segment_fn(interpret=None, combine=None):
     """Adapter for ``Engine(segment_fn=...)``: routes the local combines of
     any strategy through the Pallas kernels (the paper's 'atomic'-style
     shared-buffer update, done TPU-natively).
@@ -231,7 +239,7 @@ def make_segment_fn(interpret=not _ON_TPU, combine=None):
     return fn
 
 
-def make_push_fn(interpret=not _ON_TPU, fused=True):
+def make_push_fn(interpret=None, fused=True):
     """Adapter for ``Engine(push_fn=...)``: the whole per-chare hot loop --
     gather, edge-value transform, segment combine -- as one fused Pallas
     launch, fed by the layout's partition-time band metadata.

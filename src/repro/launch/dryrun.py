@@ -29,7 +29,6 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro import configs
 from repro.launch import hloanalysis
 from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
@@ -127,7 +126,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = int(mesh.devices.size)
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted, args = build_cell(cfg, shape, mesh)
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0
@@ -136,7 +135,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo_text = compiled.as_text()
     ana = hloanalysis.analyze(hlo_text, total_devices=chips)
     rl = roofline(ana, chips)

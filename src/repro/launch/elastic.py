@@ -19,7 +19,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.checkpoint import restore_checkpoint
 from repro.models import train as T
 
@@ -38,7 +37,7 @@ def remesh_restore(ckpt_dir: str, cfg, new_mesh, optimizer=None):
     """
     optimizer = optimizer or T.make_optimizer()
     state_shape = T.abstract_state(cfg, optimizer)
-    with compat.set_mesh(new_mesh):
+    with jax.set_mesh(new_mesh):
         specs = T.train_state_specs(state_shape, new_mesh, zero=cfg.zero)
         shardings = _named(specs, new_mesh)
         state, step = restore_checkpoint(ckpt_dir, state_shape,

@@ -385,6 +385,9 @@ def _graph_main(args):
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--smoke", action="store_true")

@@ -22,7 +22,6 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro import configs
 from repro.checkpoint import (AsyncCheckpointer, latest_step,
                               restore_checkpoint)
@@ -71,7 +70,7 @@ def train_loop(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
                                  total=steps)
     step_fn = T.make_train_step(cfg, optimizer, microbatches=microbatches)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state_shape = T.abstract_state(cfg, optimizer)
         specs = T.train_state_specs(state_shape, mesh, zero=cfg.zero)
         shardings = _named(specs, mesh)

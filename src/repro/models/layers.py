@@ -18,8 +18,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 PDT = jnp.bfloat16  # parameter/activation dtype
 
 NEG_INF = -1e30
@@ -225,8 +223,8 @@ def attention_fwd(p, x, positions, cfg, mixer):
 
 
 def _model_axis_size():
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return 1, None
     sizes = dict(mesh.shape)
     return sizes.get("model", 1), mesh
@@ -261,7 +259,7 @@ def ring_attention_block(p, x, cfg, mixer, mesh, n_model):
     G = H // KV
     Pn = n_model
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=(w_specs, x_spec),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(w_specs, x_spec),
              out_specs=x_spec, check_vma=False)
     def body(pp, x_loc):
         B, c, d = x_loc.shape

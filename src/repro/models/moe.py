@@ -32,7 +32,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models.layers import PDT, _split
 from repro.models.sharding import constrain
 
@@ -161,8 +160,8 @@ def moe_fwd_dense(p, x, cfg):
 
 
 def _model_axis_size():
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return 1, None
     sizes = dict(mesh.shape)
     return sizes.get("model", 1), mesh
@@ -200,7 +199,7 @@ def moe_fwd(p, x, cfg):
         "w_out": P("model", None, None),
     }
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=(w_specs, x_spec),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(w_specs, x_spec),
              out_specs=(x_spec, P()), check_vma=False)
     def sharded(pp, x_loc):
         Bl, Sl, dl = x_loc.shape

@@ -62,10 +62,10 @@ def resolve(logical_axes, dims, mesh) -> P:
 def constrain(x, *logical_axes):
     """with_sharding_constraint by logical names; no-op without a mesh and
     inside shard_map bodies (Manual axes -- sharding is already explicit)."""
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not mesh.axis_names:
         return x
-    if compat.mesh_has_manual_axes(mesh) or compat.in_manual_region():
+    if compat.mesh_has_manual_axes(mesh):
         return x
     spec = resolve(logical_axes, x.shape, mesh)
     return jax.lax.with_sharding_constraint(x, spec)

@@ -120,19 +120,37 @@ def test_validate_async_errors():
 
 def _one_device_grouped(x, combine, mode):
     mesh = compat.make_mesh((1,), ("pe",), axis_types=compat.auto_axes(1))
-    fn = compat.shard_map(
-        lambda v: compat.grouped_reduce(v[0], "pe", [[0]], combine,
-                                        mode=mode)[None],
+    fn = jax.shard_map(
+        lambda v: compat.grouped_reduce(
+            v[0], "pe", [[0]], combine,
+            **({} if mode is None else {"mode": mode}))[None],
         mesh=mesh, in_specs=P("pe"), out_specs=P("pe"), check_vma=False)
     return jax.jit(fn)(x[None])[0]
 
 
-@pytest.mark.parametrize("mode", ["auto", "native", "emulate"])
+@pytest.mark.parametrize("mode", [None, "native", "emulate"])
 @pytest.mark.parametrize("combine", ["add", "min"])
 def test_grouped_reduce_degenerate_group(combine, mode):
     x = jnp.asarray(np.random.default_rng(0).normal(size=16), jnp.float32)
     got = _one_device_grouped(x, combine, mode)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(x))
+
+
+def test_grouped_reduce_raises_when_native_lowering_fails(monkeypatch):
+    """No silent fallback: a failing axis_index_groups lowering surfaces
+    instead of being emulated at full-axis wire cost."""
+
+    def refuse(x, axis, axis_index_groups=None):
+        if axis_index_groups is not None:
+            raise NotImplementedError("grouped lowering unavailable")
+        return x
+
+    monkeypatch.setattr(jax.lax, "psum", refuse)
+    monkeypatch.setattr(jax.lax, "pmin", refuse)
+    for combine in ("add", "min"):
+        with pytest.raises(NotImplementedError, match="grouped"):
+            compat.grouped_reduce(jnp.zeros(4), "pe", [[0, 2], [1, 3]],
+                                  combine)
 
 
 def test_grouped_reduce_rejects_unknown_combine():

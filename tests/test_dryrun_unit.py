@@ -37,7 +37,7 @@ for arch in ("gemma2-9b", "kimi-k2-1t-a32b", "xlstm-350m", "hubert-xlarge"):
     for kind, shape in shapes.items():
         if cfg.encoder_only and kind == "decode":
             continue
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted, args = build_cell(cfg, shape, mesh)
             compiled = jitted.lower(*args).compile()
         ana = hloanalysis.analyze(compiled.as_text(), 8)

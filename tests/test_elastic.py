@@ -39,7 +39,7 @@ def mk_mesh(n):
 
 # ---- reference: uninterrupted 10-step run on 8 devices --------------------
 mesh8 = mk_mesh(8)
-with compat.set_mesh(mesh8):
+with jax.set_mesh(mesh8):
     state = T.init_state(jax.random.key(0), cfg, opt)
     step = jax.jit(T.make_train_step(cfg, opt))
     ref_losses = []
@@ -49,7 +49,7 @@ with compat.set_mesh(mesh8):
 
 # ---- elastic: 5 steps on 8 devices, checkpoint, resume on 4 ----------------
 ckdir = tempfile.mkdtemp()
-with compat.set_mesh(mesh8):
+with jax.set_mesh(mesh8):
     state = T.init_state(jax.random.key(0), cfg, opt)
     step = jax.jit(T.make_train_step(cfg, opt))
     for s in range(5):
@@ -61,7 +61,7 @@ state4, start = remesh_restore(ckdir, cfg, mesh4, optimizer=opt)
 results["resume_step"] = start
 _, mb = plan_elastic_batch(8, old_dp=8, new_dp=4)
 results["new_microbatches"] = mb
-with compat.set_mesh(mesh4):
+with jax.set_mesh(mesh4):
     step4 = jax.jit(T.make_train_step(cfg, opt, microbatches=mb))
     el_losses = []
     for s in range(start, 10):

@@ -346,3 +346,47 @@ def test_run_cost_partitions_once_per_cell():
     # (sd shared by sortdest+pairs, basic by reduction)
     assert sorted(u[1] for u in uploads) == ["basic", "sd"]
     assert len({u[0] for u in uploads}) == 1
+
+
+# ---------------------------------------------------------------------------
+# Fast-memory footprint (the shapes-only check the engine's dispatch uses)
+# ---------------------------------------------------------------------------
+
+
+def test_fused_footprint_counts_tiles_padding_and_batch():
+    fp = blocks.fused_footprint
+    body = blocks.VMEM_BODY_BYTES
+    edges4 = 4 * 2 * blocks.EDGE_ROWS * BLOCK_E * 4  # double-buffered tiles
+    # 1-D planes: [rows, 256] f32, rows padded to the 8-row sublane tile
+    got = fp(1, 256, 256)
+    assert got["vmem"] == 2 * 8 * 256 * 4 + edges4 + body
+    assert fp(1, 8 * 256, 8 * 256)["vmem"] == got["vmem"]
+    assert fp(1, 9 * 256, 9 * 256)["vmem"] == \
+        2 * 16 * 256 * 4 + edges4 + body
+    # no weight stream: one edge operand fewer; a seed plane: one more plane
+    assert fp(1, 256, 256, weight=False)["vmem"] == \
+        got["vmem"] - 2 * blocks.EDGE_ROWS * BLOCK_E * 4
+    assert fp(1, 256, 256, init=True)["vmem"] == \
+        got["vmem"] + 8 * 256 * 4
+    # batched planes: [rows, B, 256], B padded to 8 sublanes, rows unpadded
+    V = 1 << 16
+    b16 = fp(1, V, V, batch=16)["vmem"] - edges4 - body
+    assert b16 == 2 * (V // 256) * 16 * 256 * 4
+    assert fp(1, V, V, batch=3)["vmem"] == fp(1, V, V, batch=8)["vmem"]
+    # SMEM: the [4, NB] band table, NB padded to EDGE_ROWS, 512 B granules
+    assert fp(1, 256, 256)["smem"] == 512 + blocks.SMEM_RESERVE
+    assert fp(64504, 256, 256)["smem"] == \
+        4 * 64504 * 4 + 128 + blocks.SMEM_RESERVE
+
+
+def test_fused_misfit_names_the_overflowing_memory():
+    kind = "TPU v5 lite"
+    cap = blocks.TPU_FAST_MEMORY[kind]
+    assert blocks.fused_misfit({"vmem": cap["vmem"], "smem": cap["smem"]},
+                               kind) is None
+    assert blocks.fused_misfit({"vmem": cap["vmem"] + 1, "smem": 0},
+                               kind) == "vmem"
+    assert blocks.fused_misfit({"vmem": 0, "smem": cap["smem"] + 1},
+                               kind) == "smem"
+    with pytest.raises(ValueError, match="device kind"):
+        blocks.fused_misfit({"vmem": 0, "smem": 0}, "cpu")

@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.launch import hloanalysis as H
 
 SYNTH = """
@@ -80,7 +79,7 @@ def test_real_compile_matches_xla_flops():
     b = jnp.ones((32, 16), jnp.float32)
     c = jax.jit(f).lower(a, b).compile()
     ana = H.analyze(c.as_text(), total_devices=1)
-    want = compat.cost_analysis(c)["flops"]
+    want = c.cost_analysis()["flops"]
     assert abs(ana.flops - want) / want < 0.05
 
 

@@ -175,7 +175,7 @@ x = jax.random.normal(jax.random.key(1), (4, 16, 64), jnp.bfloat16)
 ref_moe, _ = MOE.moe_fwd_dense(p, x, cfg)
 mesh = compat.make_mesh((2, 4), ("data", "model"),
                         axis_types=compat.auto_axes(2))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     got_moe, _ = jax.jit(
         lambda p, x: MOE.moe_fwd(p, x, cfg),
         in_shardings=(jax.tree.map(lambda _: NamedSharding(mesh, P()), p),
@@ -197,7 +197,7 @@ s_single, m_single = jax.jit(T.make_train_step(tcfg, opt))(s_single, batch)
 
 mesh2 = compat.make_mesh((4, 2), ("data", "model"),
                          axis_types=compat.auto_axes(2))
-with compat.set_mesh(mesh2):
+with jax.set_mesh(mesh2):
     state = T.init_state(jax.random.key(0), tcfg, opt)
     specs = T.train_state_specs(jax.eval_shape(lambda: state), mesh2, zero=True)
     sh = jax.tree.map(lambda s: NamedSharding(mesh2, s), specs,
@@ -229,7 +229,7 @@ rx = jax.random.normal(jax.random.key(8), (4, 64, 48), jnp.float32)
 rref, _ = LY.attention_fwd(rp, rx, jnp.arange(64, dtype=jnp.int32), rcfg, "attn")
 rmesh = compat.make_mesh((2, 4), ("data", "model"),
                          axis_types=compat.auto_axes(2))
-with compat.set_mesh(rmesh):
+with jax.set_mesh(rmesh):
     rgot = jax.jit(
         lambda p, x: LY.ring_attention_block(p, x, rcfg, "attn", rmesh, 4),
         in_shardings=(jax.tree.map(lambda _: NamedSharding(rmesh, P()), rp),
@@ -242,12 +242,12 @@ import functools
 mesh3 = compat.make_mesh((8,), ("dp",), axis_types=compat.auto_axes(1))
 xs = jax.random.normal(jax.random.key(5), (8, 1024), jnp.float32)
 
-@functools.partial(compat.shard_map, mesh=mesh3, in_specs=P("dp"),
+@functools.partial(jax.shard_map, mesh=mesh3, in_specs=P("dp"),
                    out_specs=P("dp"), check_vma=False)
 def comp(v):
     return compressed_psum(v, "dp")[None]
 
-@functools.partial(compat.shard_map, mesh=mesh3, in_specs=P("dp"),
+@functools.partial(jax.shard_map, mesh=mesh3, in_specs=P("dp"),
                    out_specs=P("dp"), check_vma=False)
 def exact(v):
     return jax.lax.psum(v, "dp")[None]
@@ -482,11 +482,22 @@ for pname, pes in (("grid(2,2)", 4), ("grid(2,4)", 8), ("grid(4,2)", 8)):
         got, _ = run_parallel(gw, "sssp", num_pes=pes, partitioner=pname,
                               source=3, collectives=coll)
         grouped_ok &= bool(np.array_equal(np.asarray(got), sssp_ref))
-os.environ["REPRO_GROUPED"] = "emulate"
-got_e, _ = run_parallel(gw, "sssp", num_pes=8, partitioner="grid(2,4)",
-                        source=3, collectives="grouped")
-os.environ["REPRO_GROUPED"] = "auto"
-grouped_ok &= bool(np.array_equal(np.asarray(got_e), sssp_ref))
+# the group-expanded emulation (a reference asked for by name) agrees
+# with the native axis_index_groups lowering on grid(2,4)'s column groups
+import jax, jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+from repro import compat
+mesh8 = compat.make_mesh((8,), ("pe",), axis_types=compat.auto_axes(1))
+cols8 = [[c + 4 * r for r in range(2)] for c in range(4)]
+x8 = jnp.asarray(np.random.default_rng(1).normal(size=(8, 16)), jnp.float32)
+for comb in ("add", "min"):
+    outs = [jax.jit(jax.shard_map(
+        lambda v, m=m: compat.grouped_reduce(v[0], "pe", cols8, comb,
+                                             mode=m)[None],
+        mesh=mesh8, in_specs=P("pe"), out_specs=P("pe"),
+        check_vma=False))(x8) for m in ("native", "emulate")]
+    grouped_ok &= bool(np.allclose(np.asarray(outs[0]), np.asarray(outs[1]),
+                                   rtol=1e-6, atol=1e-6))
 results["grouped_ok"] = bool(grouped_ok)
 
 # ---- measured collective bytes: the grouped lowering's wire volume from

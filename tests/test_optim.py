@@ -98,7 +98,7 @@ def test_error_feedback_reduces_bias():
     import functools
     from jax.sharding import PartitionSpec as P
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(P(), P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
                        out_specs=(P(), P()), check_vma=False)
     def step(gw, rw):
         synced, new_res = ef_apply({"w": gw}, {"w": rw}, "dp")

@@ -400,8 +400,45 @@ def test_dispatch_choice_consistent_with_threshold():
             d = Engine(pg, strategy=strategy).dispatch
             want = ("fused"
                     if d["max_occupancy"] <= blocks.BAND_OCC_FUSED_MAX
+                    and d["tiles_per_edge_block"]
+                    <= blocks.FUSED_TILES_PER_BLOCK_MAX
                     else "staged")
             assert d["choice"] == want, (gname, strategy, d)
+
+
+def test_dispatch_declines_sweeps_too_wide_to_win(monkeypatch):
+    """Occupancy is relative: bands that prune well can still leave each
+    edge block more in-band tiles than the jnp push's per-edge cost
+    allows.  The rule then declines with reason "tiles", before any
+    footprint is counted, and the staged path answers."""
+    pg = G.partition(graph("rmat10"), 1)
+    d = Engine(pg).dispatch
+    assert d["choice"] == "fused" and "reason" not in d
+    assert d["tiles_per_edge_block"] == (d["tiles_fused"]
+                                         / pg.sd_band.shape[-1])
+    assert 1 < d["tiles_per_edge_block"] <= blocks.FUSED_TILES_PER_BLOCK_MAX
+    monkeypatch.setattr(blocks, "FUSED_TILES_PER_BLOCK_MAX", 1)
+    eng = Engine(pg)
+    assert eng.push_fn is None
+    assert eng.dispatch["choice"] == "staged"
+    assert eng.dispatch["reason"] == "tiles"
+    assert "footprint" not in eng.dispatch
+    got, it = eng.run("bfs", source=0)
+    want, want_it = P.bfs_serial(graph("rmat10"), 0)
+    assert np.array_equal(got, want) and it == want_it
+
+
+@pytest.mark.parametrize("scale,choice", [(14, "fused"), (16, "staged")])
+def test_dispatch_tile_rule_on_soc_lj1_stand_ins(scale, choice):
+    """The tile cap splits the soc-lj1-mini stand-ins where the chip
+    measurement put it: ~9.7 in-band tiles per edge block at 2^14
+    vertices, ~32 at 2^16, where the fused push lost to the jnp push."""
+    pg = G.partition(G.load_dataset("soc-lj1-mini", scale_log2=scale,
+                                    seed=1), 1)
+    d = Engine(pg).dispatch
+    assert d["max_occupancy"] <= blocks.BAND_OCC_FUSED_MAX
+    assert d["choice"] == choice, d
+    assert d.get("reason", "tiles") == "tiles"
 
 
 def test_layout_cost_model_reports_dispatch():
@@ -412,3 +449,61 @@ def test_layout_cost_model_reports_dispatch():
     assert cm["dispatch"]["choice"] == "fused"
     assert cm["fused"]["tiles"] == cm["dispatch"]["tiles_fused"]
     assert cm["staged"]["tiles"] == cm["dispatch"]["tiles_staged"]
+
+
+# -- fast-memory footprint check of the fused kernel (engine dispatch) -------
+
+
+def _on_v5e(monkeypatch, vmem=None, smem=None):
+    """Steer the dispatch as on a v5e: the mesh's device reports that kind,
+    optionally with shrunken fast-memory capacities."""
+    cap = dict(blocks.TPU_FAST_MEMORY["TPU v5 lite"])
+    cap.update({k: v for k, v in (("vmem", vmem), ("smem", smem))
+                if v is not None})
+    monkeypatch.setattr(blocks, "TPU_FAST_MEMORY", {"TPU v5 lite": cap})
+    monkeypatch.setattr(Engine, "_kernel_device_kind",
+                        lambda self: "TPU v5 lite")
+
+
+@pytest.mark.parametrize("space", ["vmem", "smem"])
+def test_dispatch_declines_fused_where_fast_memory_overflows(monkeypatch,
+                                                             space):
+    pg = G.partition(graph("rmat10"), 1)
+    assert Engine(pg).dispatch["choice"] == "fused"  # the band rule's pick
+    _on_v5e(monkeypatch, **{space: 1024})
+    eng = Engine(pg)
+    assert eng.push_fn is None
+    assert eng.dispatch["choice"] == "staged"
+    assert eng.dispatch["reason"] == space
+    fp = eng.dispatch["footprint"]
+    assert fp["device_kind"] == "TPU v5 lite"
+    assert fp[f"{space}_bytes"] > 1024
+    got, it = eng.run("bfs", source=0)  # the staged path still answers
+    want, want_it = P.bfs_serial(graph("rmat10"), 0)
+    assert np.array_equal(got, want) and it == want_it
+
+
+def test_dispatch_checks_each_batch_width(monkeypatch):
+    """A plane that fits at B=1 can overflow at B=16: run_batch records
+    its own decision per width and falls back to the staged push."""
+    pg = G.partition(graph("rmat10"), 1)
+    geom = dict(num_edge_blocks=blocks.num_edge_blocks(
+        pg.edge_valid.shape[1]), V=pg.chunk_size, S=pg.chunk_size)
+    one = blocks.fused_footprint(**geom)["vmem"]
+    assert blocks.fused_footprint(**geom, batch=16)["vmem"] > one
+    _on_v5e(monkeypatch, vmem=one)
+    eng = Engine(pg)
+    assert eng.dispatch["choice"] == "fused" and callable(eng.push_fn)
+    plane, iters = eng.run_batch("bfs", sources=[0, 1], batch=16)
+    rec = eng.dispatch["batch"][16]
+    assert rec["choice"] == "staged" and rec["reason"] == "vmem"
+    for i, s in enumerate((0, 1)):
+        want, want_it = P.bfs_serial(graph("rmat10"), s)
+        assert np.array_equal(plane[i], want) and iters[i] == want_it
+
+
+def test_dispatch_unknown_tpu_kind_is_an_error(monkeypatch):
+    monkeypatch.setattr(Engine, "_kernel_device_kind",
+                        lambda self: "TPU v0 unknown")
+    with pytest.raises(ValueError, match="device kind"):
+        Engine(G.partition(graph("rmat10"), 1))
