@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import collections
 import concurrent.futures
+import contextlib
 import dataclasses
 import functools
 import time
@@ -44,7 +45,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro import compat, obs
 from repro.core import partitioners as part_mod
 from repro.core import strategies as strat
 from repro.core.graph import PartitionedGraph
@@ -70,6 +71,26 @@ def _relabel_gather(init_rows, old_rows, src, tgt):
     live vertices) receive the ``src`` slots of the old plane; everything
     else keeps the caller-built init fill."""
     return init_rows.at[tgt].set(old_rows[src])
+
+
+def _apply(program, state, incoming, aux):
+    """The program's apply step, named for the profiler's op view."""
+    with jax.named_scope("graph.apply"):
+        return program.apply(state, incoming, aux)
+
+
+def _spanned(name):
+    """Wrap an ``Engine`` entry point in the host span ``name``."""
+
+    def wrap(method):
+        @functools.wraps(method)
+        def spanned(self, program, *args, **kwargs):
+            with obs.span(name, program=getattr(program, "name", program)):
+                return method(self, program, *args, **kwargs)
+
+        return spanned
+
+    return wrap
 
 
 @dataclasses.dataclass
@@ -220,7 +241,8 @@ class _StreamPrefetcher:
         if self._ex is not None:
             busy0 = self._device_busy()
             t0 = time.perf_counter()
-            slot, nbytes, dt_read, bp = item.result()
+            with obs.span("stream.wait"):
+                slot, nbytes, dt_read, bp = item.result()
             wait = max(0.0, time.perf_counter() - t0 - bp)
             busy1 = self._device_busy()
             # device-busy attribution: the wait only stalls the pipeline to
@@ -306,6 +328,8 @@ class Engine:
         self._strategy_request = (self.strategy if self.strategy != "grid2d"
                                   else "sortdest")
         self._push_request = self.push_fn
+        # compile-cache misses per program name, kept across replans
+        self._builds = collections.Counter()
         self._bind(self.pg)
 
     def _bind(self, pg: PartitionedGraph):
@@ -375,6 +399,7 @@ class Engine:
             self.arrays["gate_blocks"] = jnp.asarray(gmask)
         self.dispatch = self._resolve_dispatch()
         self.dispatch["collectives"] = self._collectives
+        self.dispatch["builds"] = self._builds
         self.dispatch["residency"] = self.residency
         if self._source is not None:
             sb = self._source
@@ -407,6 +432,18 @@ class Engine:
             raise ValueError("replan must preserve the chare count "
                              f"({pg.num_chunks} != {self._C})")
         self._bind(pg)
+
+    def _program_fn(self, key, program, make):
+        """``self._compiled[key]``, made by ``make()`` on a miss.  Returns
+        it with the context its call runs in: on a miss the call compiles,
+        so it runs in the span ``engine.build`` and the miss is counted in
+        ``dispatch['builds']``."""
+        fn = self._compiled.get(key)
+        if fn is not None:
+            return fn, contextlib.nullcontext()
+        fn = self._compiled[key] = make()
+        self._builds[program.name] += 1
+        return fn, obs.span("engine.build", program=program.name)
 
     def _kernel_device_kind(self) -> str | None:
         """``device_kind`` the fused kernel would compile for; None off-TPU,
@@ -519,16 +556,18 @@ class Engine:
     def _phase1(self, vals, arrs, combiner, edge_value=None,
                 edge_semiring=None):
         p1, _ = strat.PHASES[self.strategy]
-        return p1(vals, arrs, combiner, self._C, self._K,
-                  segment_fn=self.segment_fn, edge_value=edge_value,
-                  push_fn=self._push_for(vals), edge_semiring=edge_semiring,
-                  grid_meta=self._grid_meta)
+        with jax.named_scope("graph.push"):
+            return p1(vals, arrs, combiner, self._C, self._K,
+                      segment_fn=self.segment_fn, edge_value=edge_value,
+                      push_fn=self._push_for(vals),
+                      edge_semiring=edge_semiring, grid_meta=self._grid_meta)
 
     def _phase2(self, partial, arrs, combiner):
         _, p2 = strat.PHASES[self.strategy]
-        return p2(partial, arrs, combiner, self._C, self._K,
-                  segment_fn=self.segment_fn, grid_meta=self._grid_meta,
-                  collectives=self._collectives)
+        with jax.named_scope("graph.reduce"):
+            return p2(partial, arrs, combiner, self._C, self._K,
+                      segment_fn=self.segment_fn, grid_meta=self._grid_meta,
+                      collectives=self._collectives)
 
     def _propagate(self, vals, arrs, combiner, edge_value=None,
                    edge_semiring=None):
@@ -616,7 +655,7 @@ class Engine:
                 incoming = self._propagate(vals, arrs, comb,
                                            program.edge_value,
                                            program.edge_semiring)
-                return program.apply(state, incoming, aux)
+                return _apply(program, state, incoming, aux)
 
             if program.fixed_iters is not None:
                 final = jax.lax.fori_loop(
@@ -639,7 +678,7 @@ class Engine:
                     vals = jnp.where(frontier, program.update(state, aux),
                                      sent)
                     new_pending, active = push(vals, frontier)
-                    new = program.apply(state, incoming, aux)
+                    new = _apply(program, state, incoming, aux)
                     delta = new != state
                     changed = jax.lax.psum(
                         delta.any().astype(jnp.int32), AXIS) > 0
@@ -667,7 +706,7 @@ class Engine:
                     vals = jnp.where(frontier, program.update(state, aux),
                                      sent)
                     partial, active = push(vals, frontier)
-                    new = program.apply(state, p2(partial), aux)
+                    new = _apply(program, state, p2(partial), aux)
                     delta = new != state
                     changed = jax.lax.psum(
                         delta.any().astype(jnp.int32), AXIS) > 0
@@ -730,7 +769,7 @@ class Engine:
                     vals = jnp.where(frontier, program.update(state, aux),
                                      sent)
                     new_pending, active = push(vals, frontier)
-                    new = program.apply(state, incoming, aux)
+                    new = _apply(program, state, incoming, aux)
                     delta = new != state
                     changed = jax.lax.psum(
                         delta.any().astype(jnp.int32), AXIS) > 0
@@ -745,7 +784,7 @@ class Engine:
                      zero_sk + 1 - active0.astype(jnp.int32)))
                 # drain: fold the in-flight partial, keep its deltas in the
                 # frontier so the next segment re-pushes them
-                drained = program.apply(state, p2(pending), aux)
+                drained = _apply(program, state, p2(pending), aux)
                 frontier = frontier | (drained != state)
                 state = drained
             else:
@@ -764,7 +803,7 @@ class Engine:
                         vals = program.update(state, aux)
                         partial, active = push(vals, jnp.ones((self._K,),
                                                               bool))
-                    new = program.apply(state, p2(partial), aux)
+                    new = _apply(program, state, p2(partial), aux)
                     delta = new != state
                     if convergence:
                         changed = jax.lax.psum(
@@ -787,17 +826,17 @@ class Engine:
 
     def _run_segment(self, program, state, frontier, nsteps, sync="barrier",
                      gate=False):
-        key = (program.key, "segment", sync, gate)
-        fn = self._compiled.get(key)
-        if fn is None:
-            fn = jax.jit(self._smap(self._make_segment_body(program, sync,
-                                                            gate),
-                                    n_state_in=3, n_out=4))
-            self._compiled[key] = fn
+        fn, build = self._program_fn(
+            (program.key, "segment", sync, gate), program,
+            lambda: jax.jit(self._smap(
+                self._make_segment_body(program, sync, gate),
+                n_state_in=3, n_out=4)))
         bound = jnp.full((self._C, 1), nsteps, jnp.int32)
-        state, frontier, it, stats = fn(self.arrays, self.aux, state,
-                                        frontier, bound)
-        stats = np.asarray(jax.device_get(stats))
+        with obs.span("engine.loop"):
+            with build:
+                state, frontier, it, stats = fn(self.arrays, self.aux, state,
+                                                frontier, bound)
+            stats = np.asarray(jax.device_get(stats))
         self._gate_skipped += int(stats[:, 0].sum())
         self._gate_slots += int(stats[:, 1].sum())
         return state, frontier, int(jax.device_get(it)[0, 0])
@@ -833,6 +872,7 @@ class Engine:
         fns = self._compiled.get(key)
         if fns is not None:
             return fns
+        self._builds[program.name] += 1
         from repro.kernels import blocks as blk
 
         comb = program.combiner
@@ -884,7 +924,7 @@ class Engine:
             arrs = {k: v[0] for k, v in arrs.items()}
             aux = unpack_aux(aux, qp[0] if has_qp else None)
             incoming = self._phase2(partial[0], arrs, comb)
-            new = program.apply(state[0], incoming, aux)
+            new = _apply(program, state[0], incoming, aux)
             delta = new != state[0]
             # frontier collapsed to BLOCK_V granularity: the host-side gate
             # intersects it with each window's band source-block mask
@@ -1198,7 +1238,7 @@ class Engine:
                     vals = jnp.where(frontier, program.update(state, aux),
                                      sent)
                     new_pending, active = push(vals, frontier)
-                    new = program.apply(state, incoming, aux)
+                    new = _apply(program, state, incoming, aux)
                     delta = new != state
                     changed = active_of(delta)
                     q_quiet = jnp.where(changed, 0, q_quiet + 1)
@@ -1213,7 +1253,7 @@ class Engine:
                          jnp.zeros((B,), jnp.int32), jnp.asarray(0),
                          jnp.zeros((B,), jnp.int32),
                          zero_sk + 1 - active0.astype(jnp.int32)))
-                drained = program.apply(state, p2(pending), aux)
+                drained = _apply(program, state, p2(pending), aux)
                 frontier = frontier | (drained != state)
                 state = drained
             elif convergence:
@@ -1227,7 +1267,7 @@ class Engine:
                     vals = jnp.where(frontier,
                                      program.update(state, aux), sent)
                     partial, pushed = push(vals, frontier)
-                    new = program.apply(state, p2(partial), aux)
+                    new = _apply(program, state, p2(partial), aux)
                     delta = new != state
                     changed = active_of(delta)
                     return (new, delta, changed, it + 1,
@@ -1251,7 +1291,7 @@ class Engine:
 
                 def fstep(_, state):
                     partial, _ = push(program.update(state, aux), ones)
-                    return program.apply(state, p2(partial), aux)
+                    return _apply(program, state, p2(partial), aux)
 
                 state = jax.lax.fori_loop(0, limit, fstep, s0[0])
                 frontier = ones
@@ -1285,18 +1325,18 @@ class Engine:
 
     def _run_batch_segment(self, program, B, state, frontier, nsteps,
                            sync="barrier", gate=False, qp=None):
-        key = (self._batch_key(program, B), "segment", sync, gate)
-        fn = self._compiled.get(key)
-        if fn is None:
-            fn = jax.jit(self._smap_batch(
+        fn, build = self._program_fn(
+            (self._batch_key(program, B), "segment", sync, gate), program,
+            lambda: jax.jit(self._smap_batch(
                 self._make_batch_body(program, sync, gate),
-                qplane=program.query_plane is not None))
-            self._compiled[key] = fn
+                qplane=program.query_plane is not None)))
         bound = jnp.full((self._C, 1), nsteps, jnp.int32)
         operands = (state, frontier) + (() if qp is None else (qp,))
-        state, frontier, q_it, stats = fn(self.arrays, self.aux, *operands,
-                                          bound)
-        stats = np.asarray(jax.device_get(stats))
+        with obs.span("engine.loop"):
+            with build:
+                state, frontier, q_it, stats = fn(self.arrays, self.aux,
+                                                  *operands, bound)
+            stats = np.asarray(jax.device_get(stats))
         self._gate_skipped += int(stats[:, 0].sum())
         self._gate_slots += int(stats[:, 1].sum())
         return state, frontier, np.asarray(jax.device_get(q_it))[0]
@@ -1343,6 +1383,7 @@ class Engine:
             replans += 1
         return state, q_iters
 
+    @_spanned("engine.run_batch")
     def run_batch(self, program, sources=None, batch=None, replan=None,
                   sync="barrier", gate=None, **params
                   ) -> tuple[np.ndarray, np.ndarray]:
@@ -1407,10 +1448,11 @@ class Engine:
         if B < n:
             raise ValueError(f"batch={B} is smaller than {n} queries")
         padded = sets + (sets[0],) * (B - n)
-        state = jnp.asarray(program.init_batch(self.pg, padded))
-        frontier = jnp.ones((self._C, self._K, B), jnp.int32)
-        qp = (jnp.asarray(program.query_plane(self.pg, padded))
-              if program.query_plane is not None else None)
+        with obs.span("engine.prepare"):
+            state = jnp.asarray(program.init_batch(self.pg, padded))
+            frontier = jnp.ones((self._C, self._K, B), jnp.int32)
+            qp = (jnp.asarray(program.query_plane(self.pg, padded))
+                  if program.query_plane is not None else None)
         limit = (program.fixed_iters if program.fixed_iters is not None
                  else program.max_iters)
         self._gate_skipped = self._gate_slots = 0
@@ -1428,11 +1470,14 @@ class Engine:
         self._record_gate(sync, gate)
         # un-permute each query column to original vertex order (for grids,
         # g2l points at the column-0 replica slots)
-        plane = np.asarray(jax.device_get(state)).reshape(
-            self._C * self._K, B)[self.pg.global_to_local]
-        out = plane.T[:n].copy()
-        if program.finalize_batch is not None:
-            out = program.finalize_batch(self.pg.graph, sets, out)
+        with obs.span("engine.fetch"):
+            plane = np.asarray(jax.device_get(state))
+        with obs.span("engine.unpermute"):
+            plane = plane.reshape(self._C * self._K,
+                                  B)[self.pg.global_to_local]
+            out = plane.T[:n].copy()
+            if program.finalize_batch is not None:
+                out = program.finalize_batch(self.pg.graph, sets, out)
         return out, np.asarray(q_it[:n], np.int64)
 
     def _move_state(self, init_state, state, frontier, new_pg):
@@ -1547,8 +1592,11 @@ class Engine:
                                                frontier, new_pg)
             self._rebind(new_pg)
             replans += 1
-        final = np.asarray(jax.device_get(state)).reshape(-1)
-        return final[self.pg.global_to_local], done
+        with obs.span("engine.fetch"):
+            final = np.asarray(jax.device_get(state))
+        with obs.span("engine.unpermute"):
+            final = final.reshape(-1)[self.pg.global_to_local]
+        return final, done
 
     def _validate_async(self, program, sync, gate) -> tuple[str, bool]:
         """Normalize/validate the barrier-relaxation knobs against the
@@ -1588,6 +1636,7 @@ class Engine:
             "skipped_fraction": skipped / slots if slots else 0.0,
         }
 
+    @_spanned("engine.run")
     def run(self, program, replan=None, sync="barrier", gate=None,
             residency=None, **params) -> tuple[np.ndarray, int]:
         """Run a vertex program to completion; returns (state, iterations).
@@ -1685,26 +1734,30 @@ class Engine:
             self._record_gate(sync, gate)
             return out
 
-        key = (program.key, sync, gate)
-        s0 = jnp.asarray(program.init(self.pg))
-        fn = self._compiled.get(key)
-        if fn is None:
-            # the state buffer is consumed by the superstep loop: donate it
-            # so the loop carry reuses its allocation.  The CPU backend does
-            # not implement donation (it would only warn), so ask the mesh.
-            donate = self.mesh.devices.flat[0].platform in ("tpu", "gpu")
-            fn = jax.jit(self._smap(self._make_body(program, sync, gate),
-                                    n_out=3),
-                         donate_argnums=(2,) if donate else ())
-            self._compiled[key] = fn
-        state, iters, stats = fn(self.arrays, self.aux, s0)
-        stats = np.asarray(jax.device_get(stats))
+        # the state buffer is consumed by the superstep loop: donate it so
+        # the loop carry reuses its allocation.  The CPU backend does not
+        # implement donation (it would only warn), so ask the mesh.
+        donate = self.mesh.devices.flat[0].platform in ("tpu", "gpu")
+        with obs.span("engine.prepare"):
+            s0 = jnp.asarray(program.init(self.pg))
+            fn, build = self._program_fn(
+                (program.key, sync, gate), program,
+                lambda: jax.jit(self._smap(self._make_body(program, sync,
+                                                           gate), n_out=3),
+                                donate_argnums=(2,) if donate else ()))
+        with obs.span("engine.loop"):
+            with build:
+                state, iters, stats = fn(self.arrays, self.aux, s0)
+            stats = np.asarray(jax.device_get(stats))
         self._gate_skipped += int(stats[:, 0].sum())
         self._gate_slots += int(stats[:, 1].sum())
         self._record_gate(sync, gate)
+        with obs.span("engine.fetch"):
+            state = jax.device_get(state)
         # un-permute: padded-id state -> original vertex order (the relabel
         # invariant -- callers always see original ids; DESIGN.md sec. 7)
-        state = jax.device_get(state).reshape(-1)[self.pg.global_to_local]
+        with obs.span("engine.unpermute"):
+            state = state.reshape(-1)[self.pg.global_to_local]
         return state, int(jax.device_get(iters)[0, 0])
 
     def step_hlo(self, program, **params) -> str:
@@ -1729,7 +1782,7 @@ class Engine:
             incoming = self._propagate(program.update(s0[0], aux), arrs,
                                        comb, program.edge_value,
                                        program.edge_semiring)
-            return (program.apply(s0[0], incoming, aux)[None],)
+            return (_apply(program, s0[0], incoming, aux)[None],)
 
         s0 = jnp.asarray(program.init(self.pg))
         fn = jax.jit(self._smap(body, n_out=1))

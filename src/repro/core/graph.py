@@ -32,6 +32,7 @@ import os
 
 import numpy as np
 
+from repro import obs
 from repro.core import partitioners as part_mod
 from repro.kernels import blocks
 
@@ -265,38 +266,42 @@ class PartitionedGraph:
         placements expose the single ``grid`` layout (owners are edge
         rectangles, destinations column-padded ids)."""
         if which not in self._lazy:
-            if self.is_grid != (which == "grid"):
-                raise ValueError(
-                    f"layout {which!r} unavailable: "
-                    + ("grid partitions expose only the 'grid' layout"
-                       if self.is_grid else
-                       "the 'grid' layout needs a grid(R,C) partition"))
-            b = self._base
-            C = self.num_chunks
-            key_bound = C * b.nsb * b.nseg
-            key_dtype = INT if key_bound <= 1 << 31 else np.int64
-            owner_k = b.owner.astype(key_dtype)
-            if which == "basic":
-                # source block outermost (permuted CSR order, block-granular)
-                key = (owner_k * b.nsb + b.src_blk) * b.nseg + b.seg_blk
-            else:
-                # destination segment block outermost (the paper's
-                # dest-sorted send order, block-granular; the grid layout
-                # scatters into its narrow column block, so the same order
-                # keeps its bands tight)
-                key = (owner_k * b.nseg + b.seg_blk) * b.nsb + b.src_blk
-            if key_dtype is INT and _device_build_enabled(len(key), C,
-                                                          b.emax):
-                s, d, w, band = _build_layout_device(b, key, C)
-            else:
-                order = _stable_argsort_bounded(key, key_bound)
-                s, d, w = _pack_edges(order, b.src_local, b.dst, b.wgt,
-                                      b.owner, b.per_chunk_e, C, b.emax)
-                band = blocks.edge_bands_grouped(b.src_blk[order],
-                                                 b.seg_blk[order],
-                                                 b.per_chunk_e, b.emax)
-            self._lazy[which] = (s, d, w, band)
+            with obs.span("graph.layout", which=which):
+                self._lazy[which] = self._build_layout(which)
         return self._lazy[which]
+
+    def _build_layout(self, which: str) -> tuple:
+        if self.is_grid != (which == "grid"):
+            raise ValueError(
+                f"layout {which!r} unavailable: "
+                + ("grid partitions expose only the 'grid' layout"
+                   if self.is_grid else
+                   "the 'grid' layout needs a grid(R,C) partition"))
+        b = self._base
+        C = self.num_chunks
+        key_bound = C * b.nsb * b.nseg
+        key_dtype = INT if key_bound <= 1 << 31 else np.int64
+        owner_k = b.owner.astype(key_dtype)
+        if which == "basic":
+            # source block outermost (permuted CSR order, block-granular)
+            key = (owner_k * b.nsb + b.src_blk) * b.nseg + b.seg_blk
+        else:
+            # destination segment block outermost (the paper's
+            # dest-sorted send order, block-granular; the grid layout
+            # scatters into its narrow column block, so the same order
+            # keeps its bands tight)
+            key = (owner_k * b.nseg + b.seg_blk) * b.nsb + b.src_blk
+        if key_dtype is INT and _device_build_enabled(len(key), C,
+                                                      b.emax):
+            s, d, w, band = _build_layout_device(b, key, C)
+        else:
+            order = _stable_argsort_bounded(key, key_bound)
+            s, d, w = _pack_edges(order, b.src_local, b.dst, b.wgt,
+                                  b.owner, b.per_chunk_e, C, b.emax)
+            band = blocks.edge_bands_grouped(b.src_blk[order],
+                                             b.seg_blk[order],
+                                             b.per_chunk_e, b.emax)
+        return s, d, w, band
 
     @property
     def src_local(self) -> np.ndarray:

@@ -349,5 +349,6 @@ def fused_push(band, src, dst, valid, weight, vals, num_segments, *,
             dimension_semantics=("arbitrary",),  # out accumulates across steps
             vmem_limit_bytes=fp["vmem"]),
         interpret=interpret,
+        name=f"push_fused_{combine}",  # the monoid, for the profiler
     )(band, *operands)
     return _from_plane(out, batched)

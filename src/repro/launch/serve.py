@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
+from repro import configs, obs
 from repro.models import model as M
 from repro.models import serve as SV
 
@@ -221,6 +221,9 @@ class GraphQueryServer:
         self.policy = GreedyPolicy() if policy is None else policy
         self.clock = time.monotonic if clock is None else clock
         self._queue: deque[QueryRequest] = deque()
+        # each queued request's submit time on the span clock: its
+        # ``server.queue`` record runs from there to its admission
+        self._submitted_ns: dict[int, int] = {}
         self._results: dict[int, tuple] = {}
         self.stats: dict[int, QueryStats] = {}
         self._next_id = 0
@@ -246,6 +249,7 @@ class GraphQueryServer:
             source = int(source)
         rid = self._next_id
         self._next_id += 1
+        self._submitted_ns[rid] = obs.clock_ns()
         now = self.clock()
         self._queue.append(QueryRequest(
             rid, program, source, tuple(sorted(params.items())),
@@ -277,17 +281,24 @@ class GraphQueryServer:
         if not self._queue:
             return []
         now = self.clock()
-        admitted = self.policy.select(tuple(self._queue), self.batch, now,
-                                      self.est_dispatch, force)
+        with obs.span("server.admit") as admit:
+            admitted = self.policy.select(tuple(self._queue), self.batch,
+                                          now, self.est_dispatch, force)
+            if admitted:
+                chosen = {r.id for r in admitted}
+                self._queue = deque(r for r in self._queue
+                                    if r.id not in chosen)
         if not admitted:
             return []
-        chosen = {r.id for r in admitted}
-        self._queue = deque(r for r in self._queue if r.id not in chosen)
-        t0 = time.perf_counter()
-        plane, iters = self.engine.run_batch(
-            admitted[0].program, sources=[r.source for r in admitted],
-            batch=self.batch, **dict(admitted[0].params))
-        dt = time.perf_counter() - t0
+        n = self.dispatches
+        for r in admitted:
+            obs.record("server.queue", self._submitted_ns.pop(r.id),
+                       admit.end_ns, rid=r.id, dispatch=n)
+        with obs.span("server.dispatch", dispatch=n) as run:
+            plane, iters = self.engine.run_batch(
+                admitted[0].program, sources=[r.source for r in admitted],
+                batch=self.batch, **dict(admitted[0].params))
+        dt = run.seconds
         self.last_dispatch_s = dt
         self.dispatch_time = dt if self.dispatch_time is None \
             else 0.7 * self.dispatch_time + 0.3 * dt
@@ -299,15 +310,16 @@ class GraphQueryServer:
             self.clock.advance(dt)
         done_t = self.clock()
         self.dispatches += 1
-        for i, req in enumerate(admitted):
-            self._results[req.id] = (plane[i], int(iters[i]))
-            # expired queries are SERVED and flagged, never dropped
-            self.stats[req.id] = QueryStats(
-                id=req.id, program=req.program,
-                latency=done_t - req.submit_time, iters=int(iters[i]),
-                deadline=req.deadline,
-                deadline_missed=(req.deadline is not None
-                                 and done_t > req.deadline))
+        with obs.span("server.route", dispatch=n):
+            for i, req in enumerate(admitted):
+                self._results[req.id] = (plane[i], int(iters[i]))
+                # expired queries are SERVED and flagged, never dropped
+                self.stats[req.id] = QueryStats(
+                    id=req.id, program=req.program,
+                    latency=done_t - req.submit_time, iters=int(iters[i]),
+                    deadline=req.deadline,
+                    deadline_missed=(req.deadline is not None
+                                     and done_t > req.deadline))
         return [r.id for r in admitted]
 
     def drain(self) -> int:

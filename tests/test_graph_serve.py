@@ -336,3 +336,46 @@ def test_graph_main_qps_counts_timed_drain_only():
     assert m["drained"] == m["queries"] - m["warmup"]
     assert m["qps"] == pytest.approx(m["drained"] / m["wall_s"], rel=1e-6)
     assert m["dispatches"] >= 2 and m["p50_s"] > 0.0
+
+
+# ---------------------------------------------------------------------------
+# Spans: each admitted query's wait in the queue, each dispatch's duration
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("virtual", [False, True])
+def test_server_queue_records_one_per_admitted_query(virtual):
+    """One ``server.queue`` record per admitted query, carrying its id and
+    the dispatch it rode, timed on the span clock: a ``VirtualClock``
+    server still records the real wait."""
+    from repro import obs
+
+    obs.reset()
+    clock = VirtualClock() if virtual else None
+    _, server = _server(batch=2, clock=clock)
+    rids = [server.submit("bfs", s) for s in (1, 2, 3)]
+    time.sleep(0.02)
+    assert server.step() == rids[:2]
+    assert server.step() == rids[2:]
+    queue = obs.records("server.queue")
+    assert [(r.attrs["rid"], r.attrs["dispatch"]) for r in queue] == [
+        (rids[0], 0), (rids[1], 0), (rids[2], 1)]
+    assert all(r.seconds >= 0.02 for r in queue)
+    dispatches = obs.records("server.dispatch")
+    assert [r.attrs["dispatch"] for r in dispatches] == [0, 1]
+    # the third query waited out the first dispatch
+    assert queue[2].end_ns >= dispatches[0].end_ns
+
+
+def test_last_dispatch_s_is_the_dispatch_span():
+    from repro import obs
+
+    obs.reset()
+    _, server = _server(batch=2)
+    server.submit("bfs", 1)
+    server.step()
+    (rec,) = obs.records("server.dispatch")
+    assert server.last_dispatch_s == rec.seconds
+    assert server.dispatch_time == rec.seconds
+    (loop,) = obs.records("engine.loop")
+    assert rec.start_ns <= loop.start_ns <= loop.end_ns <= rec.end_ns

@@ -384,7 +384,7 @@ def test_dispatch_explicit_override_preserved():
     assert e.push_fn is hook
     assert e.dispatch == {"choice": "explicit", "mode": "explicit",
                           "collectives": "full",  # 1-D: nothing to group
-                          "residency": "resident"}
+                          "residency": "resident", "builds": {}}
     e2 = Engine(pg, push_fn=None)
     assert e2.push_fn is None and e2.dispatch["mode"] == "explicit"
     e3 = Engine(pg, strategy="basic")  # no push loop to fuse
@@ -507,3 +507,48 @@ def test_dispatch_unknown_tpu_kind_is_an_error(monkeypatch):
                         lambda self: "TPU v0 unknown")
     with pytest.raises(ValueError, match="device kind"):
         Engine(G.partition(graph("rmat10"), 1))
+
+
+# ---------------------------------------------------------------------------
+# Compile counter: dispatch["builds"] counts compile-cache misses per program
+# ---------------------------------------------------------------------------
+
+
+def test_builds_counts_one_per_key():
+    eng = Engine(G.partition(program_graph("sssp", REPLAN_GRAPH), 1))
+    assert eng.dispatch["builds"] == {}
+    eng.run("pagerank", iters=3)
+    assert eng.dispatch["builds"] == {"pagerank": 1}
+    eng.run("pagerank", iters=3)  # the same key: no build
+    assert eng.dispatch["builds"] == {"pagerank": 1}
+    eng.run("pagerank", iters=4)  # the iteration count is in the key
+    assert eng.dispatch["builds"] == {"pagerank": 2}
+
+
+def test_builds_one_per_bfs_source_in_run_none_in_run_batch():
+    """``Engine.run`` keys its program on the source, so each new source
+    builds; ``run_batch`` strips it, so a new source list of the same
+    width builds nothing."""
+    eng = Engine(G.partition(program_graph("bfs", REPLAN_GRAPH), 1))
+    for n, source in enumerate((0, 1, 2), start=1):
+        eng.run("bfs", source=source)
+        assert eng.dispatch["builds"]["bfs"] == n
+    eng.run("bfs", source=1)
+    assert eng.dispatch["builds"]["bfs"] == 3
+    eng.run_batch("bfs", sources=[0, 1], batch=2)
+    assert eng.dispatch["builds"]["bfs"] == 4
+    eng.run_batch("bfs", sources=[5, 9], batch=2)
+    eng.run_batch("bfs", sources=[7], batch=2)
+    assert eng.dispatch["builds"]["bfs"] == 4
+
+
+def test_builds_count_the_rebuild_after_a_replan(reverse_partitioner):
+    """``_rebind`` drops the compile cache: the segment after the replan
+    builds again, and the counter, unlike the cache, survives it."""
+    g = program_graph("sssp", REPLAN_GRAPH)
+    eng = Engine(G.partition(g, 1, "contiguous"))
+    eng.run("sssp", source=3,
+            replan=ReplanPolicy(reverse_partitioner, every=2, mode="always",
+                                max_replans=1))
+    assert eng.pg.partitioner == reverse_partitioner
+    assert eng.dispatch["builds"] == {"sssp": 2}
