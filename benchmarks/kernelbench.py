@@ -27,8 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops, ref
-from repro.kernels.blocks import (BLOCK_E, BLOCK_V, band_tiles, choose_push,
-                                  dense_grid, num_edge_blocks)
+from repro.kernels.blocks import (BLOCK_E, BLOCK_V, band_facts, band_tiles,
+                                  choose_push, dense_grid, num_edge_blocks)
 
 # launch/stage counts per push: staged = gather kernel + weight stage +
 # scatter kernel; fused = one pallas_call
@@ -112,13 +112,15 @@ def layout_cost_model(pg, layout="sd"):
     """``kernel_cost_model`` fed by a real partition's band metadata: one
     fused sweep per chare per superstep, bands summed over all chares.
     ``dispatch`` reports what ``Engine(push_fn='auto')`` would pick for this
-    layout (the adaptive staged-vs-fused rule, ``blocks.choose_push``)."""
+    layout's 1-D float-add plane, the one this model prices (the adaptive
+    staged-vs-fused rule, ``blocks.choose_push``)."""
     band = pg.sd_band if layout == "sd" else pg.band
     E, V, S = (pg.edge_valid.shape[1], pg.chunk_size,
                pg.num_chunks * pg.chunk_size)
     cm = kernel_cost_model(E=E, V=V, S=S, band=band, chares=pg.num_chunks)
-    choice, occ = choose_push(band, E, V, S)
-    cm["dispatch"] = {"choice": choice, "layout": layout, **occ}
+    facts = band_facts(band, E, V, S)
+    choice, rec = choose_push(facts, body="mxu", width=1)
+    cm["dispatch"] = {"choice": choice, "layout": layout, **facts, **rec}
     return cm
 
 

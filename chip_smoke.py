@@ -17,14 +17,16 @@ caught.  Graphs are RMAT stand-ins generated from ``--seed``.
      over the phase-A engine; two are checked bit-exact against serial.
   C. out-of-core: ``residency="stream"`` on grid(1,1), 4 edge windows, SSSP
      bit-exact with phase A, same iteration count.
-  D. the fused Pallas push on the chip: a scale-14 stand-in where ``auto``
-     picks the fused kernel and its footprint fits; the compiled step holds
+  D. the fused Pallas push on the chip: a scale-14 stand-in with the fused
+     hook pinned (``push_fn=ops.make_push_fn()``); the compiled step holds
      a ``tpu_custom_call``; PageRank, SSSP, BFS and a B=16 ``run_batch``
-     match an engine built with ``push_fn=None``.
+     match an engine built with ``push_fn=None``.  What ``auto`` would pick
+     for each of those planes is logged beside them.
 
-Every engine but phase D's reference takes the default ``push_fn="auto"``;
-at scale 22 it declines the fused kernel (``dispatch["reason"]``: too many
-in-band tiles per edge block to win, see ``blocks.choose_push``).
+Every engine but phase D's two takes the default ``push_fn="auto"``; at
+scale 22 it declines the fused kernel for every plane (``reason``
+``"tiles"``: too many in-band tiles per edge block to win, see
+``blocks.choose_push``).
 
 Earlier lines print device kind and count, per-phase set-up seconds
 (compile included) and run seconds, each engine's ``dispatch`` and the
@@ -185,15 +187,22 @@ def phase_d(args, clk, dev):
     import numpy as np
 
     from repro.core import Engine, partition
+    from repro.kernels import blocks, ops
 
     log(f"[D] fused Pallas push: scale {args.fused_scale} stand-in")
     g = clk("D.generate", weighted_graph, args.fused_scale, args.seed)
     pg = partition(g, 1)
-    fused = Engine(pg)
+    # Mosaic on the chip; the Pallas interpreter in a CPU rehearsal
+    fused = Engine(pg, push_fn=ops.make_push_fn())
     staged = Engine(pg, push_fn=None)
-    show_dispatch("D.auto", fused)
-    show_dispatch("D.staged", staged)
-    check(fused.dispatch["choice"] == "fused", fused.dispatch)
+    auto = Engine(pg)
+    show_dispatch("D.auto", auto)
+    for body, width, runs in (("mxu", 1, "pagerank"), ("vpu", 1, "sssp, bfs"),
+                              ("vpu", 16, "run_batch16")):
+        choice, rec = blocks.choose_push(auto.dispatch, body=body,
+                                         width=width)
+        log(f"  auto would push {runs} ({body}, width {width}): {choice} "
+            f"{json.dumps(rec, sort_keys=True)}")
     hlo = clk("D.step_hlo", fused.step_hlo, "pagerank")
     if args.expect_tpu:
         check("tpu_custom_call" in hlo, "no Mosaic kernel in the step")
@@ -220,10 +229,8 @@ def phase_d(args, clk, dev):
     for tag in ("compile+run", "run"):
         want, want_it = clk(f"D.run_batch16.staged({tag})",
                             staged.run_batch, "bfs", sources=srcs, batch=16)
-    check(fused.dispatch["batch"][16]["choice"] == "fused", fused.dispatch)
     check(np.array_equal(plane, want) and np.array_equal(q_it, want_it),
           "run_batch16 fused == staged")
-    show_dispatch("D.auto", fused)
     log("  fused == staged: pagerank within tolerance, sssp/bfs/run_batch16 "
         "bit-exact")
     log(f"  peak_bytes_in_use after D: {peak_bytes(dev)}")

@@ -19,9 +19,10 @@ Two adaptive layers ride on top (DESIGN.md section 9):
 
   * ``push_fn="auto"`` (the default) prices the partition's measured band
     tables (``repro.kernels.blocks.choose_push``) and picks the fused
-    band-pruned kernel vs the staged dense pipeline per layout, instead of
-    being told -- the decision is recorded in ``Engine.dispatch`` and
-    surfaced by the COST harness.
+    band-pruned kernel vs the staged dense pipeline for each plane it
+    pushes -- by the kernel body its monoid runs and the plane's width --
+    instead of being told; the decision is recorded in ``Engine.dispatch``
+    and surfaced by the COST harness.
   * ``Engine.run(replan=...)`` re-partitions mid-run: the superstep loop is
     segmented, and at segment boundaries a load-skew trigger (per-chare
     frontier-edge counts) may rebuild the placement via
@@ -273,7 +274,8 @@ class Engine:
     """Runs vertex programs on a partitioned graph with a chosen strategy.
 
     ``push_fn`` accepts ``"auto"`` (default: staged-vs-fused chosen per
-    layout from the measured band occupancy, recorded in ``self.dispatch``),
+    plane from the layout's measured bands, the kernel body and the plane's
+    width, recorded in ``self.dispatch``),
     ``None`` (explicit staged jnp pipeline), or a callable hook
     (``ops.make_push_fn``, used as given).
 
@@ -327,7 +329,6 @@ class Engine:
         # the 1-D strategy a replan back to a 1-D placement falls back to
         self._strategy_request = (self.strategy if self.strategy != "grid2d"
                                   else "sortdest")
-        self._push_request = self.push_fn
         # compile-cache misses per program name, kept across replans
         self._builds = collections.Counter()
         self._bind(self.pg)
@@ -454,30 +455,23 @@ class Engine:
     def _resolve_dispatch(self) -> dict:
         """Resolve ``push_fn='auto'`` against the bound layout's bands.
 
-        Returns the recorded decision; sets ``self.push_fn`` to the callable
-        the strategies will actually receive for a 1-D state plane (a
-        batched plane resolves its own width in ``_push_for``).  The choice
-        is the band rule (``blocks.choose_push``: occupancy and in-band
-        tiles per edge block, declining with ``reason`` ``"occupancy"`` or
-        ``"tiles"``); on a TPU the fused kernel is then installed only where
-        its fast-memory footprint (``blocks.fused_footprint``, for the
-        shapes each launch is handed) fits the chip -- otherwise the record
-        says ``staged`` with the ``reason`` ``"vmem"`` or ``"smem"``.
-        Off-TPU the kernels would only
-        run through the interpreter (an emulation, not an execution win), so
-        the staged path runs and the choice is recorded as computed.
+        Returns the layout's record: its band facts (``blocks.band_facts``:
+        occupancies and in-band tiles per edge block) with ``choice``
+        ``"by plane"``.  The choice itself is made per plane, on first use,
+        in ``_push_for``: the kernel body and plane width a launch will run
+        decide its tile cap.  A layout whose bands do not prune (``reason``
+        ``"occupancy"``) or that has no push loop to fuse records ``staged``
+        here and pushes every plane staged.
         """
         from repro.kernels import blocks
 
-        self._push_by_batch = {}
-        self._fused_geometry = None
-        if self._push_request != "auto":
-            self.push_fn = self._push_request
-            choice = "explicit" if callable(self._push_request) else "staged"
+        self._push_by_plane = {}
+        self._band_facts = self._fused_geometry = None
+        if self.push_fn != "auto":
+            choice = "explicit" if callable(self.push_fn) else "staged"
             return {"choice": choice, "mode": "explicit"}
         layout = strat.STRATEGY_LAYOUT[self.strategy]
         if layout == "pairwise":
-            self.push_fn = None
             return {"choice": "staged", "mode": "auto",
                     "reason": "basic strategy has no push loop to fuse"}
         if layout == "grid":
@@ -489,29 +483,66 @@ class Engine:
             band = self.pg.sd_band if layout == "sd" else self.pg.band
             scatter = self._C * self._K
         emax = self.pg.edge_valid.shape[1]
-        choice, occ = blocks.choose_push(band, emax, self._K, scatter)
-        rec = {"choice": choice, "mode": "auto", "layout": layout,
-               "threshold": blocks.BAND_OCC_FUSED_MAX,
-               "tiles_threshold": blocks.FUSED_TILES_PER_BLOCK_MAX, **occ}
-        if choice == "fused":
-            # a streamed launch sees one window's edge blocks and folds into
-            # a seed accumulator (init=); a resident one sees the whole row
-            stream = self._source is not None
-            self._fused_geometry = {
-                "num_edge_blocks": (self._source.blocks_per_window if stream
-                                    else blocks.num_edge_blocks(emax)),
-                "V": self._K, "S": scatter, "init": stream}
-        self.push_fn = self._resolve_push(None, rec)
+        facts = blocks.band_facts(band, emax, self._K, scatter)
+        rec = {"choice": "by plane", "mode": "auto", "layout": layout,
+               "threshold": blocks.BAND_OCC_FUSED_MAX, **facts}
+        if facts["max_occupancy"] > blocks.BAND_OCC_FUSED_MAX:
+            # the bands do not prune: no plane's sweep can win
+            rec.update(choice="staged", reason="occupancy")
+            return rec
+        self._band_facts = facts
+        # a streamed launch sees one window's edge blocks and folds into a
+        # seed accumulator (init=); a resident one sees the whole row
+        stream = self._source is not None
+        self._fused_geometry = {
+            "num_edge_blocks": (self._source.blocks_per_window if stream
+                                else blocks.num_edge_blocks(emax)),
+            "V": self._K, "S": scatter, "init": stream}
+        kind = self._kernel_device_kind()
+        if kind is not None:
+            blocks.fast_memory(kind)  # an unknown chip fails here, not later
         return rec
 
-    def _resolve_push(self, batch, rec):
+    def _push_for(self, vals, combiner):
+        """The push hook for a ``[K]`` or batched ``[K, B]`` vals plane
+        folded by ``combiner``.
+
+        Each plane -- kernel body (``blocks.push_body``), width and whether
+        it is batched -- is priced on first use: the tile cap of its body
+        and width (``blocks.choose_push``, declining with ``reason``
+        ``"tiles"``), then, on a TPU, the fused kernel's fast-memory
+        footprint for the shapes that launch is handed
+        (``blocks.fused_footprint``; ``"vmem"`` or ``"smem"`` where it does
+        not fit).  The record lands in ``dispatch['unbatched'][body]`` or
+        ``dispatch['batch'][B][body]``.  Off-TPU the kernels would only run
+        through the interpreter (an emulation, not an execution win), so
+        the staged path runs and the choice is recorded as computed.
+        """
+        from repro.kernels import blocks
+
+        if self._band_facts is None:
+            return self.push_fn if callable(self.push_fn) else None
+        body = blocks.push_body(combiner.name, vals.dtype)
+        batch = None if vals.ndim == 1 else int(vals.shape[-1])
+        if (body, batch) not in self._push_by_plane:
+            choice, rec = blocks.choose_push(self._band_facts, body=body,
+                                             width=batch or 1)
+            rec["choice"] = choice
+            hook = self._fused_hook(batch, rec) if choice == "fused" else None
+            self._push_by_plane[body, batch] = hook
+            planes = (self.dispatch.setdefault("unbatched", {})
+                      if batch is None else
+                      self.dispatch.setdefault("batch", {}).setdefault(
+                          batch, {}))
+            planes[body] = rec
+        return self._push_by_plane[body, batch]
+
+    def _fused_hook(self, batch, rec):
         """Footprint check of the fused kernel for one plane width: returns
         the hook to install (None = staged) and records the bytes, and any
         ``reason`` for declining, into ``rec``."""
         from repro.kernels import blocks
 
-        if self._fused_geometry is None:
-            return None
         fp = blocks.fused_footprint(**self._fused_geometry, batch=batch)
         rec["footprint"] = {"vmem_bytes": fp["vmem"],
                             "smem_bytes": fp["smem"]}
@@ -526,19 +557,6 @@ class Engine:
         from repro.kernels import ops
 
         return ops.make_push_fn(interpret=False)
-
-    def _push_for(self, vals):
-        """The push hook for a ``[K]`` or batched ``[K, B]`` vals plane: the
-        fused kernel's footprint grows with B, so each batch width is
-        checked on first use and recorded in ``dispatch['batch'][B]``."""
-        if vals.ndim == 1 or self._fused_geometry is None:
-            return self.push_fn
-        B = int(vals.shape[-1])
-        if B not in self._push_by_batch:
-            rec = {"choice": "fused"}
-            self._push_by_batch[B] = self._resolve_push(B, rec)
-            self.dispatch.setdefault("batch", {})[B] = rec
-        return self._push_by_batch[B]
 
     # -- shard_map plumbing -------------------------------------------------
 
@@ -559,7 +577,7 @@ class Engine:
         with jax.named_scope("graph.push"):
             return p1(vals, arrs, combiner, self._C, self._K,
                       segment_fn=self.segment_fn, edge_value=edge_value,
-                      push_fn=self._push_for(vals),
+                      push_fn=self._push_for(vals, combiner),
                       edge_semiring=edge_semiring, grid_meta=self._grid_meta)
 
     def _phase2(self, partial, arrs, combiner):
@@ -915,7 +933,7 @@ class Engine:
             out = strat.grid2d_phase1_window(
                 vals[0], wd, partial[0], comb, self._C, self._K,
                 segment_fn=self.segment_fn, edge_value=program.edge_value,
-                push_fn=self._push_for(vals[0]),
+                push_fn=self._push_for(vals[0], comb),
                 edge_semiring=program.edge_semiring,
                 grid_meta=self._grid_meta)
             return (out[None],)

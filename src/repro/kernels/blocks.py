@@ -191,14 +191,52 @@ BAND_OCC_FUSED_MAX = 0.5
 # Occupancy is relative: a band that prunes 90% of a quadratic dense grid
 # can still leave a sweep far costlier than the jnp gather/scatter push,
 # whose work grows with the edge count alone.  The fused sweep's work per
-# edge grows with the mean number of in-band tiles each edge block visits,
-# and that number grows with the vertex count on an RMAT layout (about 32
-# at 2^16 vertices, about 1,900 at 2^22).  On a TPU v5 lite at 32.4 tiles
-# per block (the scale-16 soc-lj1-mini stand-in) the fused push took 1.9x
-# (PageRank), 2.2x (SSSP) and 5.4x (BFS) the jnp push's time; at linear
-# cost in the tile count, even the add kernel breaks even below ~17.  Above
-# this cap the sweep loses for every program measured.
-FUSED_TILES_PER_BLOCK_MAX = 16
+# edge grows with the mean number of in-band tiles each edge block visits
+# (about 10 at 2^14 vertices on an RMAT layout, 32 at 2^16, 1,900 at 2^22),
+# times what one tile costs the kernel body that runs it, and that cost
+# grows with the plane's width B (a 1-D plane is width 1):
+#
+#   "mxu" (float add): one-hot matmuls; the gather serves all B query rows
+#         in one [B, 256] x [256, 256] product, the scatter takes one per row.
+#   "vpu" (min, integer add): a [256, 256] where-and-reduce per tile for
+#         every query row in turn, so B times the work of a 1-D plane.
+#
+# So the cap on in-band tiles per edge block is a function of (body, width):
+# the break-even of the fused push against the jnp push, with a margin
+# toward the jnp push.  Measured on a TPU v5 lite (scripts/push_breakeven.py,
+# warm calls on the host clock; PERF.md section 6) on the
+# soc-lj1-mini stand-ins at 2^12-2^16 vertices (3.95-32.6 tiles per block)
+# and the snb-sf10 graph (9.34), the linear break-even (tiles per block over
+# fused/staged time) read 5.2-5.8 for ("vpu", 1), 2.5-4.4 for ("vpu", 4),
+# 0.76-1.31 for ("vpu", 16), 15.0-16.3 for ("mxu", 1) on the graphs of 2^14
+# vertices and more, and 2.2-12.2 for ("mxu", 16), where the fused push lost
+# on every graph (1.4-2.7x).  A width that is not listed takes the cap of
+# the next wider width listed for its body, or of the widest: the VPU
+# body's work grows with every query row.
+FUSED_TILES_PER_BLOCK_MAX = {
+    ("mxu", 1): 15,
+    ("mxu", 16): 2,
+    ("vpu", 1): 5,
+    ("vpu", 4): 2,
+    ("vpu", 16): 0.5,
+}
+
+
+def push_body(combine: str, dtype) -> str:
+    """The fused kernel body a launch of ``combine`` over ``dtype`` values
+    runs (``push_fused.fused_push`` selects by it): "mxu" for float add,
+    "vpu" for min and integer add."""
+    if combine == "add" and not np.issubdtype(dtype, np.integer):
+        return "mxu"
+    return "vpu"
+
+
+def tiles_cap(body: str, width: int) -> float:
+    """In-band tiles per edge block above which a ``body`` launch over a
+    plane of ``width`` query rows loses to the jnp push."""
+    widths = sorted(w for b, w in FUSED_TILES_PER_BLOCK_MAX if b == body)
+    wider = [w for w in widths if w >= width]
+    return FUSED_TILES_PER_BLOCK_MAX[body, wider[0] if wider else widths[-1]]
 
 
 def dense_grid(emax: int, V: int, S: int, chares: int = 1
@@ -293,40 +331,58 @@ def fused_footprint(num_edge_blocks: int, V: int, S: int,
     return {"vmem": vmem, "smem": smem}
 
 
-def fused_misfit(footprint: dict, device_kind: str) -> str | None:
-    """Which fast memory (``"vmem"``/``"smem"``) a fused launch of this
-    ``footprint`` overflows on ``device_kind``, or None when it fits."""
+def fast_memory(device_kind: str) -> dict:
+    """``TPU_FAST_MEMORY[device_kind]``; an unlisted kind is an error."""
     if device_kind not in TPU_FAST_MEMORY:
         raise ValueError(
             f"no fast-memory capacities for device kind {device_kind!r}; "
             f"known: {sorted(TPU_FAST_MEMORY)}")
-    cap = TPU_FAST_MEMORY[device_kind]
+    return TPU_FAST_MEMORY[device_kind]
+
+
+def fused_misfit(footprint: dict, device_kind: str) -> str | None:
+    """Which fast memory (``"vmem"``/``"smem"``) a fused launch of this
+    ``footprint`` overflows on ``device_kind``, or None when it fits."""
+    cap = fast_memory(device_kind)
     for space in ("vmem", "smem"):
         if footprint[space] > cap[space]:
             return space
     return None
 
 
-def choose_push(band: np.ndarray, emax: int, V: int, S: int
-                ) -> tuple[str, dict]:
-    """-> ('fused' | 'staged', occupancy dict): the adaptive dispatch rule.
-
-    Fused when the measured bands actually prune on BOTH sides
-    (``max_occupancy <= BAND_OCC_FUSED_MAX``) and each edge block visits
-    few enough tiles (``tiles_per_edge_block <= FUSED_TILES_PER_BLOCK_MAX``);
-    staged when either side degenerates toward the dense grid -- which side
-    carries the graph signal depends on the layout's sort order, so the
-    rule prices the worse one -- or when the sweep is too wide to win.  A
-    staged choice names its ``reason``: ``"occupancy"`` or ``"tiles"``.
-    """
+def band_facts(band: np.ndarray, emax: int, V: int, S: int) -> dict:
+    """``band_occupancy`` plus the two numbers the dispatch rule reads:
+    ``max_occupancy``, the worse side's, and ``tiles_per_edge_block``, the
+    in-band tiles a fused sweep visits per edge block."""
     occ = band_occupancy(band, emax, V, S)
     occ["max_occupancy"] = max(occ["gather_occupancy"],
                                occ["scatter_occupancy"])
     chares = int(band.shape[0]) if band.ndim == 3 else 1
     occ["tiles_per_edge_block"] = occ["tiles_fused"] / max(
         chares * band.shape[-1], 1)
-    if occ["max_occupancy"] > BAND_OCC_FUSED_MAX:
-        occ["reason"] = "occupancy"
-    elif occ["tiles_per_edge_block"] > FUSED_TILES_PER_BLOCK_MAX:
-        occ["reason"] = "tiles"
-    return ("staged" if "reason" in occ else "fused"), occ
+    return occ
+
+
+def choose_push(facts: dict, *, body: str, width: int) -> tuple[str, dict]:
+    """-> ('fused' | 'staged', record): the adaptive dispatch rule applied
+    to a layout's ``band_facts``, for a plane of ``width`` query rows (1 for
+    a 1-D plane) pushed by the kernel ``body`` (``push_body``).
+
+    Fused when the measured bands actually prune on BOTH sides
+    (``max_occupancy <= BAND_OCC_FUSED_MAX``) and each edge block visits
+    few enough tiles (``tiles_per_edge_block <= tiles_cap(body, width)``);
+    staged when either side degenerates toward the dense grid -- which side
+    carries the graph signal depends on the layout's sort order, so the
+    rule prices the worse one -- or when the sweep is too wide to win.  The
+    record holds ``body``, ``width``, ``tiles_per_edge_block`` and the
+    ``cap`` applied; a staged choice names its ``reason``: ``"occupancy"``
+    or ``"tiles"``.
+    """
+    rec = {"body": body, "width": width,
+           "tiles_per_edge_block": facts["tiles_per_edge_block"],
+           "cap": tiles_cap(body, width)}
+    if facts["max_occupancy"] > BAND_OCC_FUSED_MAX:
+        rec["reason"] = "occupancy"
+    elif facts["tiles_per_edge_block"] > rec["cap"]:
+        rec["reason"] = "tiles"
+    return ("staged" if "reason" in rec else "fused"), rec

@@ -269,18 +269,16 @@ def fused_push(band, src, dst, valid, weight, vals, num_segments, *,
         raise ValueError("unit_weight replaces the weight operand")
     weight_mode = "array" if weight is not None else \
         ("unit" if unit_weight else "none")
-    integer = jnp.issubdtype(vals.dtype, jnp.integer)
-    if combine == "add":
-        if weight_mode == "unit":
-            weight_mode = "none"  # multiplying by 1 is the identity
-        out_dtype = vals.dtype if integer \
-            else jnp.promote_types(vals.dtype, jnp.float32)
-        body = (functools.partial(_vpu_kernel, combine="add",
-                                  weight_mode=weight_mode) if integer
-                else _mxu_add_kernel)
+    if combine != "add":
+        combine = "min"
+    elif weight_mode == "unit":
+        weight_mode = "none"  # multiplying by 1 is the identity
+    if blocks.push_body(combine, vals.dtype) == "mxu":
+        out_dtype = jnp.promote_types(vals.dtype, jnp.float32)
+        body = _mxu_add_kernel
     else:
         out_dtype = vals.dtype
-        body = functools.partial(_vpu_kernel, combine="min",
+        body = functools.partial(_vpu_kernel, combine=combine,
                                  weight_mode=weight_mode)
     have_w = weight_mode == "array"
     have_init = init is not None

@@ -215,7 +215,7 @@ def test_engine_strategy_follows_partition():
     eng = Engine(pg, strategy="reduction")
     assert eng.strategy == "grid2d"
     assert eng.dispatch["layout"] == "grid"
-    assert eng.dispatch["choice"] in ("fused", "staged")
+    assert eng.dispatch["choice"] in ("by plane", "staged")
     # grid2d on a 1-D partition is an error
     with pytest.raises(ValueError, match="grid"):
         Engine(G.partition(g, 1), strategy="grid2d")

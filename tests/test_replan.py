@@ -17,7 +17,8 @@ import pytest
 
 from conftest import (ALL_PARTITIONERS, graph, program_graph, race,
                       serial_ref, source_params)
-from repro.core import Engine, ReplanPolicy, get_spec, run_parallel
+from repro.core import (Engine, ReplanPolicy, get_spec, pagerank_serial,
+                        run_parallel)
 from repro.core import graph as G
 from repro.core import partitioners as PT
 from repro.core import programs as P
@@ -344,8 +345,10 @@ def test_auto_dispatch_fused_on_rmat_staged_on_uniform():
     }
     d_rmat = reports["rmat"].dispatch[("contiguous", "sortdest", 1)]
     d_uni = reports["uniform"].dispatch[("contiguous", "sortdest", 1)]
-    assert d_rmat["choice"] == "fused"
-    assert d_uni["choice"] == "staged"
+    assert d_rmat["choice"] == "by plane"
+    assert d_rmat["unbatched"]["mxu"]["choice"] == "fused"  # PageRank's
+    assert d_uni["choice"] == "staged" and d_uni["reason"] == "occupancy"
+    assert "unbatched" not in d_uni
     # the decision is the measured worst-side occupancy vs the threshold
     assert d_rmat["max_occupancy"] <= blocks.BAND_OCC_FUSED_MAX
     assert d_uni["max_occupancy"] > blocks.BAND_OCC_FUSED_MAX
@@ -392,53 +395,75 @@ def test_dispatch_explicit_override_preserved():
 
 
 def test_dispatch_choice_consistent_with_threshold():
-    """The engine's recorded choice always equals the threshold rule applied
-    to its own recorded occupancy, for every layout/strategy that fuses."""
+    """The engine's recorded choices always equal the rule applied to its
+    own records, for every layout/strategy that fuses: the layout's
+    occupancy against the threshold, then each plane's in-band tiles per
+    edge block against the cap of its kernel body and width."""
     for gname in ("rmat6", "rmat10", "ring12"):
         pg = G.partition(graph(gname), 1)
         for strategy in ("sortdest", "reduction", "pairs"):
-            d = Engine(pg, strategy=strategy).dispatch
-            want = ("fused"
-                    if d["max_occupancy"] <= blocks.BAND_OCC_FUSED_MAX
-                    and d["tiles_per_edge_block"]
-                    <= blocks.FUSED_TILES_PER_BLOCK_MAX
-                    else "staged")
-            assert d["choice"] == want, (gname, strategy, d)
+            eng = Engine(pg, strategy=strategy)
+            eng.run("pagerank", iters=1)
+            eng.run("bfs", source=0)
+            eng.run_batch("bfs", sources=[0, 1], batch=16)
+            d = eng.dispatch
+            if d["max_occupancy"] > blocks.BAND_OCC_FUSED_MAX:
+                assert d["choice"] == "staged", (gname, strategy, d)
+                assert "unbatched" not in d and "batch" not in d
+                continue
+            assert d["choice"] == "by plane", (gname, strategy, d)
+            planes = [*d["unbatched"].values(), *d["batch"][16].values()]
+            assert {(r["body"], r["width"]) for r in planes} == {
+                ("mxu", 1), ("vpu", 1), ("vpu", 16)}
+            for rec in planes:
+                cap = blocks.tiles_cap(rec["body"], rec["width"])
+                want = ("fused" if d["tiles_per_edge_block"] <= cap
+                        else "staged")
+                assert rec["cap"] == cap, (gname, strategy, rec)
+                assert rec["tiles_per_edge_block"] == d["tiles_per_edge_block"]
+                assert rec["choice"] == want, (gname, strategy, rec)
 
 
 def test_dispatch_declines_sweeps_too_wide_to_win(monkeypatch):
     """Occupancy is relative: bands that prune well can still leave each
     edge block more in-band tiles than the jnp push's per-edge cost
     allows.  The rule then declines with reason "tiles", before any
-    footprint is counted, and the staged path answers."""
+    footprint is counted, and the staged path answers (on a v5e, where a
+    fused pick would install the Mosaic kernel)."""
     pg = G.partition(graph("rmat10"), 1)
     d = Engine(pg).dispatch
-    assert d["choice"] == "fused" and "reason" not in d
+    assert d["choice"] == "by plane" and "reason" not in d
     assert d["tiles_per_edge_block"] == (d["tiles_fused"]
                                          / pg.sd_band.shape[-1])
-    assert 1 < d["tiles_per_edge_block"] <= blocks.FUSED_TILES_PER_BLOCK_MAX
-    monkeypatch.setattr(blocks, "FUSED_TILES_PER_BLOCK_MAX", 1)
+    assert 1 < d["tiles_per_edge_block"] <= blocks.tiles_cap("vpu", 1)
+    monkeypatch.setattr(blocks, "FUSED_TILES_PER_BLOCK_MAX", {
+        plane: 1 for plane in blocks.FUSED_TILES_PER_BLOCK_MAX})
+    _on_v5e(monkeypatch)
     eng = Engine(pg)
-    assert eng.push_fn is None
-    assert eng.dispatch["choice"] == "staged"
-    assert eng.dispatch["reason"] == "tiles"
-    assert "footprint" not in eng.dispatch
     got, it = eng.run("bfs", source=0)
+    rec = eng.dispatch["unbatched"]["vpu"]
+    assert rec["choice"] == "staged" and rec["reason"] == "tiles"
+    assert rec["cap"] == 1
+    assert "footprint" not in rec
     want, want_it = P.bfs_serial(graph("rmat10"), 0)
     assert np.array_equal(got, want) and it == want_it
 
 
 @pytest.mark.parametrize("scale,choice", [(14, "fused"), (16, "staged")])
 def test_dispatch_tile_rule_on_soc_lj1_stand_ins(scale, choice):
-    """The tile cap splits the soc-lj1-mini stand-ins where the chip
-    measurement put it: ~9.7 in-band tiles per edge block at 2^14
-    vertices, ~32 at 2^16, where the fused push lost to the jnp push."""
+    """The float-add 1-D plane's tile cap splits the soc-lj1-mini
+    stand-ins where the chip measurement put it: ~9.7 in-band tiles per
+    edge block at 2^14 vertices, ~32 at 2^16, where the fused push lost to
+    the jnp push."""
     pg = G.partition(G.load_dataset("soc-lj1-mini", scale_log2=scale,
                                     seed=1), 1)
-    d = Engine(pg).dispatch
+    eng = Engine(pg)
+    eng.run("pagerank", iters=1)
+    d = eng.dispatch
     assert d["max_occupancy"] <= blocks.BAND_OCC_FUSED_MAX
-    assert d["choice"] == choice, d
-    assert d.get("reason", "tiles") == "tiles"
+    rec = d["unbatched"]["mxu"]  # the float-add 1-D plane
+    assert rec["choice"] == choice, d
+    assert rec.get("reason", "tiles") == "tiles"
 
 
 def test_layout_cost_model_reports_dispatch():
@@ -469,37 +494,109 @@ def _on_v5e(monkeypatch, vmem=None, smem=None):
 def test_dispatch_declines_fused_where_fast_memory_overflows(monkeypatch,
                                                              space):
     pg = G.partition(graph("rmat10"), 1)
-    assert Engine(pg).dispatch["choice"] == "fused"  # the band rule's pick
+    # the band rule's pick for the float-add 1-D plane (PageRank)
+    assert blocks.choose_push(Engine(pg).dispatch, body="mxu",
+                              width=1)[0] == "fused"
     _on_v5e(monkeypatch, **{space: 1024})
     eng = Engine(pg)
-    assert eng.push_fn is None
-    assert eng.dispatch["choice"] == "staged"
-    assert eng.dispatch["reason"] == space
-    fp = eng.dispatch["footprint"]
+    got, _ = eng.run("pagerank", iters=5)  # the staged path still answers
+    rec = eng.dispatch["unbatched"]["mxu"]
+    assert rec["choice"] == "staged"
+    assert rec["reason"] == space
+    fp = rec["footprint"]
     assert fp["device_kind"] == "TPU v5 lite"
     assert fp[f"{space}_bytes"] > 1024
-    got, it = eng.run("bfs", source=0)  # the staged path still answers
-    want, want_it = P.bfs_serial(graph("rmat10"), 0)
-    assert np.array_equal(got, want) and it == want_it
+    want = pagerank_serial(graph("rmat10"), iters=5)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
+
+
+def _block_rings(n=4096):
+    """A ring inside each BLOCK_V-vertex block: every edge block touches one
+    source and one segment block, the narrowest bands a graph can have."""
+    src = np.arange(n)
+    width = blocks.BLOCK_V
+    dst = src // width * width + (src % width + 1) % width
+    return G.from_edges(n, src, dst, directed=True)
 
 
 def test_dispatch_checks_each_batch_width(monkeypatch):
     """A plane that fits at B=1 can overflow at B=16: run_batch records
-    its own decision per width and falls back to the staged push."""
-    pg = G.partition(graph("rmat10"), 1)
+    its own decision per width and falls back to the staged push.  The
+    bands are narrow enough for the float-add body (personalized PageRank)
+    at both widths, so the footprint decides."""
+    pg = G.partition(_block_rings(), 1)
     geom = dict(num_edge_blocks=blocks.num_edge_blocks(
         pg.edge_valid.shape[1]), V=pg.chunk_size, S=pg.chunk_size)
     one = blocks.fused_footprint(**geom)["vmem"]
     assert blocks.fused_footprint(**geom, batch=16)["vmem"] > one
     _on_v5e(monkeypatch, vmem=one)
+    assert blocks.fused_misfit(blocks.fused_footprint(**geom),
+                               "TPU v5 lite") is None
     eng = Engine(pg)
-    assert eng.dispatch["choice"] == "fused" and callable(eng.push_fn)
-    plane, iters = eng.run_batch("bfs", sources=[0, 1], batch=16)
-    rec = eng.dispatch["batch"][16]
+    assert eng.dispatch["tiles_per_edge_block"] <= blocks.tiles_cap("mxu", 16)
+    seeds = [(0,), (1,)]
+    plane, _ = eng.run_batch("personalized_pagerank", sources=seeds,
+                             batch=16)
+    rec = eng.dispatch["batch"][16]["mxu"]
     assert rec["choice"] == "staged" and rec["reason"] == "vmem"
+    want, _ = Engine(pg, push_fn=None).run_batch(
+        "personalized_pagerank", sources=seeds, batch=16)
+    assert np.array_equal(plane, want)
+
+
+def test_dispatch_b16_min_plane_declines_by_tiles(monkeypatch):
+    """The VPU body's work grows with the plane's width: on rmat10 the B=16
+    min plane is over the VPU cap at width 16, so it declines with reason
+    "tiles" before any footprint is counted (on a v5e, where a fused pick
+    would install the Mosaic kernel), and the staged path answers."""
+    pg = G.partition(graph("rmat10"), 1)
+    _on_v5e(monkeypatch)
+    eng = Engine(pg)
+    tiles = eng.dispatch["tiles_per_edge_block"]
+    assert blocks.tiles_cap("vpu", 16) < tiles
+    plane, iters = eng.run_batch("bfs", sources=[0, 1], batch=16)
+    assert eng.dispatch["batch"][16]["vpu"] == {
+        "body": "vpu", "width": 16, "tiles_per_edge_block": tiles,
+        "cap": blocks.tiles_cap("vpu", 16), "choice": "staged",
+        "reason": "tiles"}
     for i, s in enumerate((0, 1)):
         want, want_it = P.bfs_serial(graph("rmat10"), s)
         assert np.array_equal(plane[i], want) and iters[i] == want_it
+
+
+def test_dispatch_float_add_plane_keeps_fused_on_the_same_bands():
+    """The same bands pushed by the MXU body on a 1-D float-add plane
+    (PageRank) stay under that body's cap: the rule picks fused."""
+    eng = Engine(G.partition(graph("rmat10"), 1))
+    eng.run("pagerank", iters=1)
+    rec = eng.dispatch["unbatched"]["mxu"]
+    assert rec["choice"] == "fused" and "reason" not in rec
+    assert rec["cap"] == blocks.tiles_cap("mxu", 1)
+    assert rec["tiles_per_edge_block"] <= rec["cap"]
+
+
+@pytest.mark.parametrize("combine,dtype,body", [
+    ("add", np.float32, "mxu"), ("add", "bfloat16", "mxu"),
+    ("add", np.int32, "vpu"), ("min", np.int32, "vpu"),
+    ("min", np.float32, "vpu")])
+def test_push_body_follows_the_kernel(combine, dtype, body):
+    """The body the rule prices is the one ``fused_push`` runs."""
+    import jax.numpy as jnp
+
+    assert blocks.push_body(combine, jnp.dtype(dtype)) == body
+
+
+def test_tiles_cap_unlisted_width_takes_the_next_wider():
+    caps = blocks.FUSED_TILES_PER_BLOCK_MAX
+    for body in ("mxu", "vpu"):
+        widths = sorted(w for b, w in caps if b == body)
+        for w in widths:
+            assert blocks.tiles_cap(body, w) == caps[body, w]
+        for lo, hi in zip(widths, widths[1:]):
+            if hi - lo > 1:
+                assert blocks.tiles_cap(body, lo + 1) == caps[body, hi]
+        assert blocks.tiles_cap(body, 4 * widths[-1]) == caps[
+            body, widths[-1]]
 
 
 def test_dispatch_unknown_tpu_kind_is_an_error(monkeypatch):
