@@ -362,8 +362,10 @@ class Engine:
         elif self.strategy in strat.PAIRWISE:
             self.arrays = self.pg.device_pairwise()
         else:
+            # a grid layout may be built on the mesh itself, one rectangle
+            # per device (``PartitionedGraph.grid_build_site``)
             self.arrays = self.pg.device_arrays(
-                strat.STRATEGY_LAYOUT[self.strategy])
+                strat.STRATEGY_LAYOUT[self.strategy], mesh=self.mesh)
         self.aux = self.pg.device_aux()
         if pg.is_grid:
             rows, cols = pg.grid_shape
@@ -402,6 +404,13 @@ class Engine:
         self.dispatch["collectives"] = self._collectives
         self.dispatch["builds"] = self._builds
         self.dispatch["residency"] = self.residency
+        if pg.is_grid:
+            self.dispatch["grid"] = {
+                "rows": rows, "cols": cols, "row_chunk": self._K,
+                "col_chunk": pg.col_chunk_size,
+                "collectives": self._collectives,
+                "layout": ("stream" if self._source is not None
+                           else pg.grid_build_site(self.mesh))}
         if self._source is not None:
             sb = self._source
             cfg = self.stream or StreamConfig()

@@ -28,6 +28,7 @@ load-imbalance observations.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 
 import numpy as np
@@ -112,13 +113,20 @@ class Graph:
 
 def from_edges(n: int, src: np.ndarray, dst: np.ndarray, directed=True,
                weight: np.ndarray | None = None) -> Graph:
-    """Build CSR from a COO edge list (sorts by src, keeps duplicates)."""
+    """Build CSR from a COO edge list (sorts by src, stably, unless it is
+    sorted already; keeps duplicates)."""
     src = np.asarray(src, dtype=INT)
-    dst = np.asarray(dst, dtype=INT)
-    order = np.argsort(src, kind="stable")
-    src, dst = src[order], dst[order]
-    if weight is not None:
-        weight = np.asarray(weight, dtype=WEIGHT)[order]
+    if np.all(src[1:] >= src[:-1]):
+        # already in source order, as generators deliver it: the stable
+        # argsort would be the identity, so the graph takes plain copies
+        dst = np.array(dst, dtype=INT)
+        if weight is not None:
+            weight = np.array(weight, dtype=WEIGHT)
+    else:
+        order = np.argsort(src, kind="stable")
+        src, dst = src[order], np.asarray(dst, dtype=INT)[order]
+        if weight is not None:
+            weight = np.asarray(weight, dtype=WEIGHT)[order]
     counts = np.bincount(src, minlength=n)
     indptr = np.zeros(n + 1, dtype=np.int64)
     np.cumsum(counts, out=indptr[1:])
@@ -183,7 +191,11 @@ class PartitionedGraph:
     from the shared relabeled-edge base and is then cached.  ``partition``
     forces both layouts up front (its callers expect a fully built
     decomposition); ``repartition`` leaves them lazy, so a mid-run replan
-    pays only for the one layout its strategy actually reads.
+    pays only for the one layout its strategy actually reads.  A grid
+    layout that a resident engine would build on its devices, one
+    rectangle per device (``grid_build_site``), is left to that engine by
+    ``partition`` too: its planes are then made on the devices that hold
+    them and never exist on the host.
 
     2-D grid partitions (``GridPlan`` placements, DESIGN.md section 10)
     reuse the same container with "chare" meaning "edge rectangle": one
@@ -212,10 +224,12 @@ class PartitionedGraph:
     plan: object = None  # the PartitionPlan this layout materializes
     # relabeled-edge base (_EdgeBase) both layout builds consume
     _base: object = dataclasses.field(default=None, repr=False, compare=False)
-    # demand-materialized layout cache: "basic"/"sd" -> (src, dst, w, band)
+    # demand-materialized layout cache: "basic"/"sd"/"grid" -> (src, dst,
+    # w, band); "grid_band" -> the band of a grid layout built on devices
     _lazy: dict = dataclasses.field(default_factory=dict, repr=False,
                                     compare=False)
-    # device-upload cache (keyed "dense:basic"/"dense:sd"/"pairwise"/"aux"):
+    # device-upload cache (keyed "dense:basic"/"dense:sd"/"pairwise"/"aux",
+    # "sharded:grid:<device ids>" for a grid layout built on its devices):
     # engines built on the same partition share one resident copy of every
     # layout buffer, so a PE/strategy sweep uploads each layout once
     _dev: dict = dataclasses.field(default_factory=dict, repr=False,
@@ -266,9 +280,14 @@ class PartitionedGraph:
         placements expose the single ``grid`` layout (owners are edge
         rectangles, destinations column-padded ids)."""
         if which not in self._lazy:
-            with obs.span("graph.layout", which=which):
+            grid = ({"where": "host", "rect_edges": self._rect_edges()}
+                    if which == "grid" else {})
+            with obs.span("graph.layout", which=which, **grid):
                 self._lazy[which] = self._build_layout(which)
         return self._lazy[which]
+
+    def _rect_edges(self) -> list:
+        return [int(n) for n in self._base.per_chunk_e]
 
     def _build_layout(self, which: str) -> tuple:
         if self.is_grid != (which == "grid"):
@@ -356,6 +375,9 @@ class PartitionedGraph:
 
     @property
     def gr_band(self) -> np.ndarray:
+        if "grid" not in self._lazy and "grid_band" in self._lazy:
+            # the layout was built on the devices; this is its band table
+            return self._lazy["grid_band"]
         return self._layout("grid")[3]
 
     @property
@@ -386,15 +408,40 @@ class PartitionedGraph:
                 flat, minlength=P * K).astype(np.int64).reshape(P, K)
         return self._lazy["rect_degree"]
 
-    def device_arrays(self, layout: str = "both") -> dict:
+    def grid_build_site(self, mesh=None) -> str:
+        """Where the grid layout an engine on ``mesh`` reads is built:
+        ``"sharded"``, each rectangle sorted, packed and banded on the mesh
+        device that holds it (``_build_grid_sharded``), or ``"host"``, the
+        host build uploaded.  Sharded takes a grid placement, a mesh of one
+        device per rectangle, a graph past the device-build threshold
+        (``_device_build_enabled``) and sort keys that fit int32."""
+        if mesh is None or mesh.devices.size != self.num_chunks:
+            return "host"
+        return "sharded" if self._grid_builds_on_devices() else "host"
+
+    def _grid_builds_on_devices(self) -> bool:
+        b = self._base
+        return (self.is_grid and b.nseg * b.nsb < 1 << 31
+                and _device_build_enabled(len(b.dst), self.num_chunks,
+                                          b.emax))
+
+    def device_arrays(self, layout: str = "both", mesh=None) -> dict:
         """Device-resident dense layout arrays (edge order + band metadata),
         uploaded once per partition and shared by every Engine built on it.
 
         ``layout`` is ``"basic"``, ``"sd"``, ``"grid"``, or ``"both"``:
         engines ask for their strategy's layout only
         (``strategies.STRATEGY_LAYOUT``), so a replan uploads -- and
-        materializes -- just what it will run.
+        materializes -- just what it will run.  A grid layout for an engine
+        on ``mesh`` is built on the mesh's devices where
+        ``grid_build_site`` says so: its planes never visit the host.
         """
+        if layout == "grid" and self.grid_build_site(mesh) == "sharded":
+            key = "sharded:grid:" + ",".join(
+                str(d.id) for d in mesh.devices.flat)
+            if key not in self._dev:
+                self._dev[key] = _build_grid_sharded(self, mesh)
+            return self._dev[key]
         if layout == "both":
             if self.is_grid:
                 return self.device_arrays("grid")
@@ -741,9 +788,10 @@ def _pack_edges(order_idx, src_local, dst, wgt, owner, per_chunk_e,
 
 
 # Edge count above which the layout build (stable argsort + rectangle pack +
-# band reduction) runs on device instead of the host radix path: scale-20
-# stand-ins cross it, every CI-sized graph stays on the host build.  Override
-# with REPRO_DEVICE_BUILD=device|host (auto = threshold).
+# band reduction) runs on device instead of the host radix path -- for a
+# resident grid engine, on the device of each rectangle: scale-20 stand-ins
+# cross it, every CI-sized graph stays on the host build.  Override with
+# REPRO_DEVICE_BUILD=device|host (auto = threshold).
 _DEVICE_BUILD_MIN_EDGES = 1 << 21
 
 
@@ -823,6 +871,110 @@ def _build_layout_device(b: "_EdgeBase", key: np.ndarray, C: int) -> tuple:
             np.ascontiguousarray(w[:, :b.emax]), band)
 
 
+def grid_build_program(mesh, emax: int, nsb: int, nseg: int,
+                       weighted: bool):
+    """The jitted per-rectangle layout build over ``mesh``'s one axis:
+    ``(counts [P], src [P, Emax], dst [P, Emax][, weight [P, Emax]])`` ->
+    the packed ``(src, dst, valid, weight)`` planes and the ``[P, 4, NB]``
+    band table, every array sharded one rectangle row per device.
+
+    Each device sorts its own row by (segment block, source block) -- the
+    host build's key less its constant owner term -- with the slots past
+    the rectangle's edge count keyed past every real key, so the stable
+    sort leaves the packed row with the host build's fill (0, 0, 1.0) in
+    its tail; the band table is taken over the sorted row.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as Spec
+
+    nb = blocks.num_edge_blocks(emax)
+    key_fill = nseg * nsb  # past every real key
+    axis = mesh.axis_names[0]
+
+    def body(n, s, d, *w):
+        n, s, d = n[0], s[0], d[0]
+        valid = jnp.arange(emax, dtype=INT) < n
+        key = jnp.where(valid, (d // blocks.BLOCK_S) * nsb
+                        + s // blocks.BLOCK_V, key_fill)
+        _, s, d, *w = jax.lax.sort((key, s, d, *(x[0] for x in w)),
+                                   num_keys=1, is_stable=True)
+        w = w[0] if w else jnp.ones(emax, WEIGHT)
+
+        # band min/max over BLOCK_E columns, padding filled so it never
+        # wins, then the (0, -1, 0, -1) empty-block clamp
+        def blk(x, fill):
+            x = jnp.pad(jnp.where(valid, x, fill),
+                        (0, nb * blocks.BLOCK_E - emax), constant_values=fill)
+            return x.reshape(nb, blocks.BLOCK_E)
+
+        big = jnp.int32(1) << 30
+        band = []
+        for x in (s // blocks.BLOCK_V, d // blocks.BLOCK_S):
+            lo, hi = blk(x, big).min(axis=1), blk(x, -1).max(axis=1)
+            band += [jnp.where(hi < 0, 0, lo), hi]
+        return (s[None], d[None], valid.astype(INT)[None], w[None],
+                jnp.stack(band)[None])
+
+    edge = Spec(axis, None)
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(Spec(axis),) + (edge,) * (3 if weighted else 2),
+        out_specs=(edge,) * 4 + (Spec(axis, None, None),)))
+
+
+def _build_grid_sharded(pg: PartitionedGraph, mesh) -> dict:
+    """The grid layout built where it lives: rectangle k's stable sort,
+    pack and band table run on the mesh device that holds shard k
+    (``grid_build_program``).
+
+    The host only groups the edges by rectangle, keeping their CSR order
+    (one stable pass over the small owner ids), and uploads each
+    rectangle's row-local sources, column-padded destinations and, for a
+    weighted graph, weights to its own device, padded to the common
+    ``Emax``.  Planes, validity and band equal the host build's bit for
+    bit (tests/test_grid_sharded.py).  Only the band table, a few ints per
+    edge block, comes back to the host (``gr_band``), for the dispatch
+    and gating decisions.
+    """
+    import jax
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as Spec
+
+    b = pg._base
+    P, emax = pg.num_chunks, b.emax
+    axis = mesh.axis_names[0]
+    rows = NamedSharding(mesh, Spec(axis, None))
+    weighted = pg.graph.weight is not None
+    counts = b.per_chunk_e
+    with obs.span("graph.layout", which="grid", where="sharded",
+                  rect_edges=pg._rect_edges()):
+        order = np.argsort(b.owner, kind="stable")
+        starts = blocks.rect_bounds(counts)[0]
+
+        def upload(values, fill, dtype):
+            def shard(index):
+                ks = np.arange(P)[index[0]]
+                out = np.full((len(ks), emax), fill, dtype)
+                for i, k in enumerate(ks):
+                    np.take(values, order[starts[k]:starts[k] + counts[k]],
+                            out=out[i, :counts[k]])
+                return out
+            return jax.make_array_from_callback((P, emax), rows, shard)
+
+        build = grid_build_program(mesh, emax, b.nsb, b.nseg, weighted)
+        n = jax.device_put(counts.astype(INT),
+                           NamedSharding(mesh, Spec(axis)))
+        planes = [upload(b.src_local, 0, INT), upload(b.dst, 0, INT)]
+        if weighted:
+            planes.append(upload(b.wgt, 1.0, WEIGHT))
+        s, d, valid, w, band = jax.block_until_ready(build(n, *planes))
+        pg._lazy["grid_band"] = np.asarray(jax.device_get(band))
+    return {"gr_src_local": s, "gr_dst_col": d, "gr_edge_valid": valid,
+            "gr_edge_weight": w, "gr_band": band,
+            "gr_row_to_col": jax.device_put(pg.gr_row_to_col, rows)}
+
+
 @dataclasses.dataclass(frozen=True)
 class _EdgePrep:
     """Plan-independent prep products, computed once per graph and shared by
@@ -858,7 +1010,9 @@ def partition(graph: Graph, num_chunks: int,
     ``eager=False`` defers the edge-layout builds to first use -- the entry
     point for the disk layout cache (``cached_layout``/``shard_source``): a
     deferred partition whose layout comes off a warm cache entry never runs
-    the sort/pack build at all.
+    the sort/pack build at all.  A grid layout that an engine would build
+    on its devices (``PartitionedGraph.grid_build_site``) is deferred even
+    when eager: the engine knows whether it streams or holds the edges.
     """
     plan = part_mod.make_plan(graph, num_chunks, partitioner)
     return _materialize(graph, plan, partitioner, _edge_prep(graph), eager)
@@ -886,10 +1040,21 @@ class _EdgeBase:
     owner: np.ndarray  # [E] owning chunk/rectangle of each edge
     per_chunk_e: np.ndarray  # [C]
     emax: int
-    src_blk: np.ndarray  # [E] gather-side tile id (local source / BLOCK_V)
-    seg_blk: np.ndarray  # [E] scatter-side tile id (scatter dest / BLOCK_S)
     nsb: int  # gather-side tile count per owner
     nseg: int  # scatter-side tile count
+
+    # the tile ids are host passes over every edge; a layout built on the
+    # devices computes its own there, so these are made on first host use
+
+    @functools.cached_property
+    def src_blk(self) -> np.ndarray:
+        """[E] gather-side tile id (local source / BLOCK_V)."""
+        return self.src_local // blocks.BLOCK_V
+
+    @functools.cached_property
+    def seg_blk(self) -> np.ndarray:
+        """[E] scatter-side tile id (scatter destination / BLOCK_S)."""
+        return self.dst // blocks.BLOCK_S
 
 
 @dataclasses.dataclass(frozen=True)
@@ -907,7 +1072,8 @@ def _materialize(graph: Graph, plan, partitioner: str, prep: _EdgePrep,
     """Build the chare decomposition for one ``PartitionPlan``.
 
     ``eager`` forces both edge layouts (``partition``'s contract: a fully
-    built decomposition); ``repartition`` passes ``eager=False`` so a replan
+    built decomposition; a grid layout built on the devices waits for its
+    engine); ``repartition`` passes ``eager=False`` so a replan
     materializes only the layout its engine strategy reads, on demand.
     ``GridPlan`` placements route to ``_materialize_grid``.
     """
@@ -939,8 +1105,6 @@ def _materialize(graph: Graph, plan, partitioner: str, prep: _EdgePrep,
     edge_valid = (np.arange(emax) < per_chunk_e[:, None]).astype(INT)
     src_local = src - owner * chunk_size
     base = _EdgeBase(src_local, dst, prep.wgt, owner, per_chunk_e, emax,
-                     src_blk=src_local // blocks.BLOCK_V,
-                     seg_blk=dst // blocks.BLOCK_S,
                      nsb=-(-chunk_size // blocks.BLOCK_V),
                      nseg=-(-padded // blocks.BLOCK_S))
 
@@ -963,6 +1127,24 @@ def _materialize(graph: Graph, plan, partitioner: str, prep: _EdgePrep,
         pg._layout("basic")
         pg._layout("sd")
     return pg
+
+
+def _grid_row_width(rect_counts: np.ndarray) -> int:
+    """Row width of a grid layout: its largest rectangle, or 1% over the
+    mean rectangle where that is wider, placed half a 1024-element tile
+    past a multiple of 1024.
+
+    A balanced placement's largest rectangle lies within 1% of the mean
+    and moves with the seed by a few thousand edges; the floor gives every
+    seed of a configuration one shape, so one compiled layout build and
+    one compiled program over the planes.  The offset is the compiler's:
+    XLA lays out the push's gather indices differently when a plane's
+    length is a multiple of 1024 or lies within 128 below one, and on a
+    TPU v5e that layout ran the push about 3% slower.
+    """
+    n = max(int(rect_counts.max()), -(-int(rect_counts.sum()) * 101
+                                      // (100 * len(rect_counts))))
+    return -(-(n + 512) // 1024) * 1024 - 512
 
 
 def _materialize_grid(graph: Graph, plan, partitioner: str, prep: _EdgePrep,
@@ -1002,19 +1184,15 @@ def _materialize_grid(graph: Graph, plan, partitioner: str, prep: _EdgePrep,
     row_to_col = np.full(R * Kr, -1, dtype=INT)
     row_to_col[live] = col_g2l[row_l2g[live]].astype(INT)
 
-    # relabel edges: row-local gather index, column-padded scatter id,
-    # owning rectangle
-    src_row = row_g2l.astype(INT)[prep.src]
-    src_local = src_row % Kr
+    # relabel edges: row-local gather index, column-padded scatter id; the
+    # owning rectangle of each edge is the plan's
+    src_local = row_g2l.astype(INT)[prep.src] % Kr
     dst_col = col_g2l.astype(INT)[prep.dst]
-    owner = blocks.edge_rectangles(src_row // Kr, dst_col // Kc, C)
-    per_rect_e = np.bincount(owner, minlength=P)
-    emax = max(int(per_rect_e.max()) if len(src_local) else 1, 1)
+    per_rect_e = plan.rect_counts
+    emax = _grid_row_width(per_rect_e)
     edge_valid = (np.arange(emax) < per_rect_e[:, None]).astype(INT)
-    base = _EdgeBase(src_local, dst_col, prep.wgt, owner, per_rect_e, emax,
-                     src_blk=src_local // blocks.BLOCK_V,
-                     seg_blk=dst_col // blocks.BLOCK_S,
-                     nsb=-(-Kr // blocks.BLOCK_V),
+    base = _EdgeBase(src_local, dst_col, prep.wgt, plan.edge_rect,
+                     per_rect_e, emax, nsb=-(-Kr // blocks.BLOCK_V),
                      nseg=-(-(C * Kc) // blocks.BLOCK_S))
 
     pg = PartitionedGraph(
@@ -1034,7 +1212,13 @@ def _materialize_grid(graph: Graph, plan, partitioner: str, prep: _EdgePrep,
         _grid=_GridMeta(R, C, Kc, rep(row_to_col)),
     )
     if eager:
-        pg._layout("grid")
+        import jax
+
+        # where a resident engine on this host would build the layout on
+        # its devices (``grid_build_site``), the engine, which knows the
+        # residency, builds it; otherwise the host build runs now
+        if not (jax.device_count() >= P and pg._grid_builds_on_devices()):
+            pg._layout("grid")
     return pg
 
 
@@ -1158,7 +1342,7 @@ def rmat(n_log2: int, num_edges: int, seed: int = 0,
 _DATASETS = {
     # name: (n_log2, edge_multiple-of-V)   paper: V, E, E/V
     "soc-lj1-mini": (15, 14),   # soc-LiveJournal1: 4.8M, 69M, 14.2x
-    "twitter-mini": (15, 24),   # twitter_rv: 61.6M, 1.47B, 23.8x
+    "twitter-mini": (15, 24),   # twitter_rv: 61.6M ids, 1.47B, 23.8x
     "uk-2007-mini": (15, 35),   # uk-2007-05: 105.9M, 3.74B, 35.3x
 }
 

@@ -177,7 +177,8 @@ class GridPlan:
     ``rect_starts``/``rect_counts`` are the per-rectangle edge bounds: in the
     rectangle-sorted edge order, rectangle k owns edges
     ``[rect_starts[k], rect_starts[k] + rect_counts[k])`` and the bounds tile
-    ``[0, E)``.
+    ``[0, E)``.  ``edge_rect`` is the rectangle of every edge in the graph's
+    CSR order, computed once here and reused as the layout's edge owners.
     """
 
     rows: int
@@ -185,6 +186,8 @@ class GridPlan:
     row: "PartitionPlan"
     col: "PartitionPlan"
     rect_counts: np.ndarray  # [rows*cols] int64 edges per rectangle
+    # [E] flat rectangle id per edge, the smallest int type that holds it
+    edge_rect: np.ndarray = dataclasses.field(repr=False, compare=False)
 
     @property
     def num_chunks(self) -> int:
@@ -283,10 +286,12 @@ def _grid_spec(name: str, m: "re.Match") -> PartitionerSpec:
         col = PARTITIONERS[col_policy].plan(graph, C)
         from repro.kernels import blocks
 
-        rect = blocks.edge_rectangles(row.vertex_chunk[graph.src],
-                                      col.vertex_chunk[graph.dst], C)
+        small = np.min_scalar_type(-R * C)  # holds every rectangle id
+        rect = blocks.edge_rectangles(
+            row.vertex_chunk.astype(small)[graph.src],
+            col.vertex_chunk.astype(small)[graph.dst], C)
         counts = np.bincount(rect, minlength=R * C).astype(np.int64)
-        return GridPlan(R, C, row, col, counts)
+        return GridPlan(R, C, row, col, counts, rect)
 
     return PartitionerSpec(
         name, plan,

@@ -111,9 +111,10 @@ def edge_rectangles(row_of_src: np.ndarray, col_of_dst: np.ndarray,
     Rectangle ``(r, c)`` has flat id ``r*cols + c`` -- the row-major order
     the engine's shard axis uses (one shard per rectangle), chosen so that a
     grid column is the strided set ``c, c+cols, ...`` and a grid row is the
-    contiguous run ``r*cols .. r*cols+cols-1``.
+    contiguous run ``r*cols .. r*cols+cols-1``.  The ids keep the inputs'
+    integer type, which must hold ``R*cols``.
     """
-    return row_of_src.astype(np.int64) * cols + col_of_dst
+    return row_of_src * cols + col_of_dst
 
 
 def rect_bounds(rect_counts: np.ndarray) -> tuple[np.ndarray, np.ndarray]:

@@ -326,9 +326,9 @@ def test_run_cost_partitions_once_per_cell():
     uploads = []
     orig = PartitionedGraph.device_arrays
 
-    def counting(self, layout="both"):
+    def counting(self, layout="both", mesh=None):
         first = layout != "both" and f"dense:{layout}" not in self._dev
-        out = orig(self, layout)
+        out = orig(self, layout, mesh)
         if first:
             uploads.append((id(self), layout))
         return out

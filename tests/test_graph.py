@@ -20,6 +20,28 @@ def test_from_edges_roundtrip():
     assert got == sorted(zip(src.tolist(), dst.tolist()))
 
 
+def test_from_edges_sorted_input_skips_the_argsort(monkeypatch):
+    rng = np.random.default_rng(3)
+    src = rng.integers(0, 50, 400).astype(np.int32)
+    dst = rng.integers(0, 50, 400).astype(np.int32)
+    w = rng.random(400).astype(np.float32)
+    want = G.from_edges(50, src, dst, weight=w)  # unsorted: argsorts
+    order = np.argsort(src, kind="stable")
+    s, d, ws = src[order], dst[order], w[order]
+    calls = []
+    orig = np.argsort
+    monkeypatch.setattr(np, "argsort",
+                        lambda *a, **k: calls.append(1) or orig(*a, **k))
+    got = G.from_edges(50, s, d, weight=ws)
+    assert calls == []
+    assert np.array_equal(got.indptr, want.indptr)
+    assert np.array_equal(got.dst, want.dst)
+    assert np.array_equal(got.weight, want.weight)
+    # the graph holds its own arrays, as the sorting path's gathers did
+    assert not np.shares_memory(got.dst, d)
+    assert not np.shares_memory(got.weight, ws)
+
+
 def test_partition_preserves_edges_and_weights():
     g = G.rmat(5, 120, seed=4, weighted=True)
     want = sorted(zip(g.src.tolist(), g.dst.tolist(),

@@ -5,7 +5,8 @@ described in the ``topo`` fixture (skipped where it cannot be described),
 from shapes alone.  Each case is compiled at the largest size
 ``blocks.fused_footprint`` admits on a v5e and must contain the Mosaic
 ``tpu_custom_call``; the rejection cases hold the footprint check to the
-compiler, which must refuse shapes the check declines.
+compiler, which must refuse shapes the check declines.  The grid layout's
+per-rectangle build is compiled for the four chips of the described host.
 
 Only a fixture touches the TPU library -- never an import, a ``skipif`` or
 a ``parametrize`` -- and the persistent compilation cache is off around
@@ -133,3 +134,30 @@ def test_footprint_rejection_is_a_compiler_refusal(one_chip, space):
     assert blocks.fused_misfit(fp, KIND) == space
     with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
         _compile(one_chip, nb, V, "min", jnp.float32, weight=True)
+
+
+def test_grid_sharded_build_compiles_for_four_chips(topo):
+    """The per-rectangle layout build (``graph.grid_build_program``) over a
+    2x2 host's four chips, at the ``twitter-rv.grid22`` cell's row width
+    (6,313,472 edges of an unweighted graph; 2**19-vertex row and column
+    chunks): each chip's sort, pack and band table fit its 16 GB many
+    times over.  (The TPU sort takes most of a minute to compile,
+    so the weighted build, one sort operand more, is not compiled here.)"""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as Spec
+
+    from repro.core.graph import grid_build_program
+
+    mesh = Mesh(np.array(topo.devices), ("pe",))
+    emax, k = 6_313_472, 1 << 19
+    fn = grid_build_program(mesh, emax, k // BLOCK_V, 2 * k // BLOCK_V,
+                            weighted=False)
+    row = NamedSharding(mesh, Spec("pe", None))
+    args = [jax.ShapeDtypeStruct((4,), jnp.int32,
+                                 sharding=NamedSharding(mesh, Spec("pe")))]
+    args += [jax.ShapeDtypeStruct((4, emax), jnp.int32, sharding=row)] * 2
+    mem = fn.lower(*args).compile().memory_analysis()
+    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+    assert per_chip < 2**30
